@@ -33,8 +33,9 @@ import time
 from repro.core import (AutoscaleConfig, AutoscalePolicy, ClusterRuntime,
                         DormMaster, OptimizerConfig, PolicyTimer,
                         RecordingProtocol, SLOMonitor, TraceConfig,
-                        fairness_budget, generate_trace,
-                        heterogeneous_cluster, signals_from_workload)
+                        configure_compile_cache, fairness_budget,
+                        generate_trace, heterogeneous_cluster,
+                        signals_from_workload)
 
 from .common import emit
 
@@ -197,6 +198,7 @@ def main() -> None:
     ap.add_argument("--json", default="BENCH_autoscale.json",
                     help="output path for the JSON report ('' disables)")
     args = ap.parse_args()
+    configure_compile_cache()
     print("name,value,unit,notes")
     run(n_slaves=args.slaves, n_apps=args.apps, seed=args.seed,
         horizon_s=args.horizon_h * 3600.0, tick_s=args.tick_s,

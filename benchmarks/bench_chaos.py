@@ -40,11 +40,11 @@ import json
 import time
 
 from repro.core import (ChaosConfig, ChaosMonitor, ClusterSimulator,
-                        DormMaster, DRFScheduler, OptimizerConfig,
-                        Reallocated, RecordingProtocol, StaticScheduler,
-                        TetrisScheduler, TraceConfig, chaos_config_hash,
-                        chaos_schedule, container_churn, generate_trace,
-                        heterogeneous_cluster)
+                        DormMaster, DRFScheduler, OptimizerConfig, Reallocated,
+                        RecordingProtocol, StaticScheduler, TetrisScheduler,
+                        TraceConfig, chaos_config_hash, chaos_schedule,
+                        configure_compile_cache, container_churn,
+                        generate_trace, heterogeneous_cluster)
 
 from .common import emit
 
@@ -182,6 +182,7 @@ def main() -> None:
     ap.add_argument("--json", default="BENCH_chaos.json",
                     help="output path for the JSON report ('' disables)")
     args = ap.parse_args()
+    configure_compile_cache()
     print("name,value,unit,notes")
     run(n_slaves=args.slaves, n_apps=args.apps, seed=args.seed,
         horizon_s=args.horizon_h * 3600.0,

@@ -34,7 +34,8 @@ import time
 import numpy as np
 
 from repro.core import (ClusterSimulator, DormMaster, OptimizerConfig,
-                        RecordingProtocol, TraceConfig, fairness_budget,
+                        RecordingProtocol, TraceConfig,
+                        configure_compile_cache, fairness_budget,
                         generate_trace, heterogeneous_cluster)
 
 from .common import emit
@@ -168,6 +169,7 @@ def main() -> None:
     ap.add_argument("--json", default="BENCH_goodput.json",
                     help="output path for the JSON report ('' disables)")
     args = ap.parse_args()
+    configure_compile_cache()
     print("name,value,unit,notes")
     run(n_slaves=args.slaves, n_apps=args.apps, seed=args.seed,
         horizon_s=args.horizon_h * 3600.0,

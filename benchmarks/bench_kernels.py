@@ -9,6 +9,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.core import configure_compile_cache
 from repro.kernels import ops
 
 from .common import emit
@@ -61,4 +62,5 @@ def run():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     run()

@@ -45,9 +45,9 @@ import numpy as np
 from repro.core import (AbsorberConfig, ClusterSimulator, DormMaster,
                         GreedyOptimizer, OptimizerConfig, PolicyTimer,
                         Reallocated, RecordingProtocol, TraceConfig,
-                        container_churn, generate_trace,
-                        heterogeneous_cluster, make_optimizer, replay_trace,
-                        resource_utilization)
+                        configure_compile_cache, container_churn,
+                        generate_trace, heterogeneous_cluster, make_optimizer,
+                        replay_trace, resource_utilization)
 
 from .common import emit
 
@@ -249,6 +249,7 @@ def main() -> None:
     ap.add_argument("--json", default="BENCH_replay.json",
                     help="output path for the JSON report ('' disables)")
     args = ap.parse_args()
+    configure_compile_cache()
     print("name,value,unit,notes")
     run(n_slaves=args.slaves, n_apps=args.apps, seed=args.seed,
         trace=args.trace, fmt=args.fmt, horizon_s=args.horizon_h * 3600.0,

@@ -9,8 +9,7 @@ absolute milliseconds across runs/machines -- only in-process ratios):
                           kept behind `OptimizerConfig(soa=False)`)
   * soa full re-solve  -- the seed's full per-event re-solve semantics
   * jax incremental    -- the SoA engine on `OptimizerConfig(backend=
-                          "jax")` (jit/lax scheduler kernels; skipped when
-                          jax is not importable)
+                          "jax")` (jit/lax scheduler kernels)
 
 All allocation timelines must be bit-exact (the SoA engine, the delta
 path and the jax backend are pure optimizations); the per-event
@@ -50,8 +49,9 @@ import time
 from repro.core import (AutoBackend, ClusterSimulator, DormMaster,
                         MilpOptimizer, OptimizerConfig, PolicyTimer,
                         Reallocated, RecordingProtocol, TraceConfig,
-                        backend_available, container_churn, generate_trace,
-                        heterogeneous_cluster, resource_utilization)
+                        configure_compile_cache, container_churn,
+                        generate_trace, heterogeneous_cluster,
+                        resource_utilization)
 
 from .common import emit
 
@@ -150,7 +150,7 @@ def exact_head_to_head(n_slaves: int, n_apps: int, seed: int,
     return out
 
 
-def _same_timeline(a, b, exact_metrics: bool = True) -> bool:
+def same_timeline(a, b, exact_metrics: bool = True) -> bool:
     """Same event times/counts/durations; metric floats compared exactly or
     to 1e-9 (the SoA engine sums Eq-2 with pairwise float reduction, which
     can differ from the legacy sequential sum in the last ulp)."""
@@ -183,23 +183,18 @@ def run(n_slaves: int = 1000, n_apps: int = 500, seed: int = 0,
     inc, res_inc = _run_once(cluster, wl, True, *args, soa=True)
     leg, res_leg = _run_once(cluster, wl, True, *args, soa=False)
     full, res_full = _run_once(cluster, wl, False, *args, soa=True)
-    have_jax = backend_available("jax")
-    jx = res_jx = None
-    if have_jax:
-        jx, res_jx = _run_once(cluster, wl, True, *args, soa=True,
-                               backend="jax")
-    bit_exact = _same_timeline(res_inc, res_full)
-    bit_exact_engines = _same_timeline(res_inc, res_leg,
-                                       exact_metrics=False)
-    bit_exact_jax = (_same_timeline(res_inc, res_jx)
-                     if res_jx is not None else None)
+    jx, res_jx = _run_once(cluster, wl, True, *args, soa=True,
+                           backend="jax")
+    bit_exact = same_timeline(res_inc, res_full)
+    bit_exact_engines = same_timeline(res_inc, res_leg,
+                                      exact_metrics=False)
+    bit_exact_jax = same_timeline(res_inc, res_jx)
     speedup = full["per_event_policy_ms_median"] / max(
         inc["per_event_policy_ms_median"], 1e-9)
     soa_speedup = leg["per_event_policy_ms_median"] / max(
         inc["per_event_policy_ms_median"], 1e-9)
     jax_ratio = (jx["per_event_policy_ms_median"]
-                 / max(inc["per_event_policy_ms_median"], 1e-9)
-                 if jx is not None else None)
+                 / max(inc["per_event_policy_ms_median"], 1e-9))
 
     # NOTE: notes must stay comma-free -- common.emit writes unquoted CSV.
     phases = inc["phases_s"]
@@ -240,18 +235,15 @@ def run(n_slaves: int = 1000, n_apps: int = 500, seed: int = 0,
         ("scale.container_churn", inc["container_churn"], "count",
          "containers created+destroyed"),
     ]
-    if jx is not None:
-        rows += [
-            ("scale.policy_ms_jax_median",
-             jx["per_event_policy_ms_median"], "ms",
-             "median per-event; jax backend; compiles excluded"),
-            ("scale.jax_median_ratio", jax_ratio, "x",
-             f"jax/numpy per-event medians; bit_exact={bit_exact_jax}"),
-            ("scale.jax_compile_s", jx["backend_compile_s"], "s",
-             "cumulative first-touch jit compile time"),
-        ]
-    else:
-        rows += [("scale.jax_median_ratio", "", "x", "jax unavailable")]
+    rows += [
+        ("scale.policy_ms_jax_median",
+         jx["per_event_policy_ms_median"], "ms",
+         "median per-event; jax backend; compiles excluded"),
+        ("scale.jax_median_ratio", jax_ratio, "x",
+         f"jax/numpy per-event medians; bit_exact={bit_exact_jax}"),
+        ("scale.jax_compile_s", jx["backend_compile_s"], "s",
+         "cumulative first-touch jit compile time"),
+    ]
 
     # backend="auto" crossover record: the dispatcher's live thresholds and
     # which delegate it picks at this scale and at xl (5000x2000) -- the
@@ -261,7 +253,6 @@ def run(n_slaves: int = 1000, n_apps: int = 500, seed: int = 0,
     backend_auto = {
         "crossover_slaves": auto_be.crossover_slaves,
         "crossover_apps": auto_be.crossover_apps,
-        "jax_available": have_jax,
         "picks_at_bench_scale": auto_be._pick(
             n_slaves, auto_be.crossover_slaves).name,
         "picks_at_xl_scale": auto_be._pick(
@@ -339,26 +330,25 @@ def run(n_slaves: int = 1000, n_apps: int = 500, seed: int = 0,
             ("scale.xl_completed", xl_res["completed"], "count",
              f"of {xl_apps}"),
         ]
-        if have_jax:
-            xl_jax, _ = _run_once(xl_cluster, xl_wl, True, horizon_s,
-                                  batch_window_s, theta1, theta2,
-                                  auto_switch_vars, soa=True,
-                                  backend="jax")
-            xl_ratio = (xl_jax["per_event_policy_ms_median"]
-                        / max(xl_res["per_event_policy_ms_median"], 1e-9))
-            payload["xl_jax"] = xl_jax
-            payload["xl_jax_median_ratio"] = xl_ratio
-            rows += [
-                ("scale.xl_jax_policy_ms_median",
-                 xl_jax["per_event_policy_ms_median"], "ms",
-                 f"{xl_slaves}x{xl_apps} per-event median; jax backend"),
-                ("scale.xl_jax_median_ratio", xl_ratio, "x",
-                 "jax/numpy per-event medians at xl; compiles excluded"),
-                ("scale.xl_jax_compile_s", xl_jax["backend_compile_s"],
-                 "s", "cumulative first-touch jit compile time"),
-                ("scale.xl_jax_completed", xl_jax["completed"], "count",
-                 f"of {xl_apps}"),
-            ]
+        xl_jax, _ = _run_once(xl_cluster, xl_wl, True, horizon_s,
+                              batch_window_s, theta1, theta2,
+                              auto_switch_vars, soa=True,
+                              backend="jax")
+        xl_ratio = (xl_jax["per_event_policy_ms_median"]
+                    / max(xl_res["per_event_policy_ms_median"], 1e-9))
+        payload["xl_jax"] = xl_jax
+        payload["xl_jax_median_ratio"] = xl_ratio
+        rows += [
+            ("scale.xl_jax_policy_ms_median",
+             xl_jax["per_event_policy_ms_median"], "ms",
+             f"{xl_slaves}x{xl_apps} per-event median; jax backend"),
+            ("scale.xl_jax_median_ratio", xl_ratio, "x",
+             "jax/numpy per-event medians at xl; compiles excluded"),
+            ("scale.xl_jax_compile_s", xl_jax["backend_compile_s"],
+             "s", "cumulative first-touch jit compile time"),
+            ("scale.xl_jax_completed", xl_jax["completed"], "count",
+             f"of {xl_apps}"),
+        ]
 
     emit(rows)
     if json_path:
@@ -384,6 +374,7 @@ def main() -> None:
     ap.add_argument("--json", default="BENCH_scale.json",
                     help="output path for the JSON report ('' disables)")
     args = ap.parse_args()
+    configure_compile_cache()
     print("name,value,unit,notes")
     run(n_slaves=args.slaves, n_apps=args.apps, seed=args.seed,
         horizon_s=args.horizon_h * 3600.0,

@@ -51,11 +51,12 @@ import time
 from types import SimpleNamespace
 
 from repro.core import (AbsorberConfig, ChaosConfig, ClusterRuntime,
-                        ClusterSpec, Coordinator, OptimizerConfig,
-                        PolicyTimer, Reallocated, ResourceVector,
-                        ShardConfig, ShardedControlPlane, TraceConfig,
-                        cross_shard_certificate, forced_churn_attribution,
-                        generate_trace, heterogeneous_cluster)
+                        ClusterSpec, Coordinator, OptimizerConfig, PolicyTimer,
+                        Reallocated, ResourceVector, ShardConfig,
+                        ShardedControlPlane, TraceConfig,
+                        configure_compile_cache, cross_shard_certificate,
+                        forced_churn_attribution, generate_trace,
+                        heterogeneous_cluster)
 
 from .common import emit
 
@@ -283,6 +284,7 @@ def main() -> None:
     ap.add_argument("--json", default="BENCH_shard.json",
                     help="output path for the JSON report ('' disables)")
     args = ap.parse_args()
+    configure_compile_cache()
     print("name,value,unit,notes")
     run(n_slaves=args.slaves, n_apps=args.apps, seed=args.seed,
         n_shards=args.shards, horizon_s=args.horizon_h * 3600.0,

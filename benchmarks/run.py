@@ -12,6 +12,8 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.core import configure_compile_cache
+
 from . import (bench_autoscale, bench_chaos, bench_goodput, bench_kernels,
                bench_replay, bench_scale, bench_shard, fig1_durations,
                fig6_utilization, fig7_fairness, fig8_adjustment,
@@ -36,6 +38,7 @@ MODULES = {
 
 def main() -> None:
     names = sys.argv[1:] or list(MODULES)
+    configure_compile_cache()
     print("name,value,unit,notes")
     for n in names:
         t0 = time.time()

@@ -32,10 +32,10 @@ and booked under `DormMaster.phase_breakdown()["backend_compile"]` --
 `PolicyTimer` subtracts them from per-event latencies, so medians stay
 honest.
 
-On real TPUs the placement inner loop additionally dispatches to a Pallas
-kernel (`repro.kernels.placement.best_fit_counts`, a sort-free O(b^2)
-rank-compare reduction); everywhere else the `lax` composition runs in
-float64 and carries the bitwise guarantee.
+On a TPU the placement inner loop is the compiled Pallas kernel
+(`repro.kernels.placement.best_fit_counts`, a sort-free O(b^2)
+rank-compare reduction); on other platforms it is the `lax` argsort
+composition. jax is required: this example does not fall back to numpy.
 
 Run:  PYTHONPATH=src python examples/jax_backend.py [--slaves 120 --apps 60]
 """
@@ -46,8 +46,7 @@ import numpy as np
 
 from repro.core import (ClusterSimulator, DormMaster, OptimizerConfig,
                         PolicyTimer, RecordingProtocol, TraceConfig,
-                        backend_available, generate_trace,
-                        heterogeneous_cluster)
+                        generate_trace, heterogeneous_cluster)
 
 
 def run_backend(backend: str, cluster, wl, horizon_s: float):
@@ -83,9 +82,6 @@ def main() -> None:
     horizon_s = args.horizon_h * 3600.0
 
     res_np, _ = run_backend("numpy", cluster, wl, horizon_s)
-    if not backend_available("jax"):
-        print("jax not installed -- numpy backend only")
-        return
     res_jx, m_jx = run_backend("jax", cluster, wl, horizon_s)
 
     # The two timelines must be indistinguishable, sample for sample.

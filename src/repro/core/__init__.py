@@ -5,7 +5,8 @@ from .adjustment import (AdjustmentEvent, AdjustmentProtocol, CheckpointHandle,
 from .autoscale import (AutoscaleConfig, AutoscalePolicy, LoadSignal,
                         ReplayLoadSignal, SLOMonitor, signals_from_workload)
 from .backend import (AutoBackend, Backend, JaxBackend, NumpyBackend,
-                      auto_dispatch_report, backend_available, get_backend)
+                      auto_dispatch_report, configure_compile_cache,
+                      get_backend)
 from .baselines import (MESOS_SCHED_LATENCY_S, DRFScheduler, StaticScheduler,
                         TaskLevelOverheadModel, TetrisScheduler)
 from .chaos import (ChaosConfig, ChaosMonitor, chaos_config_hash,
@@ -51,7 +52,7 @@ from .workload import (BASELINE_STATIC_CONTAINERS, MEAN_INTERARRIVAL_S,
 
 __all__ = [
     "AutoBackend", "Backend", "JaxBackend", "NumpyBackend",
-    "auto_dispatch_report", "backend_available", "get_backend",
+    "auto_dispatch_report", "configure_compile_cache", "get_backend",
     "Coordinator", "Migrate", "ShardConfig", "ShardedControlPlane",
     "TetrisScheduler", "cross_shard_certificate", "partition_cluster",
     "utilization_objective",

@@ -13,9 +13,10 @@ explicit seam under them:
   * `JaxBackend`    -- the same three kernels as `jax.jit` programs built
     on `lax` (stable argsort + clipped-cumsum scatter, `lax.scan` for the
     inherently sequential grant loop, `lax.while_loop` for the ladder's
-    exhaustion passes). On TPU the placement inner loop dispatches to the
-    Pallas kernel in `repro.kernels.placement`; everywhere else the lax
-    composition is the fallback.
+    exhaustion passes). On a TPU the placement inner loop is the compiled
+    Pallas kernel in `repro.kernels.placement`; on other platforms it is
+    the lax argsort composition. jax is a hard dependency: asking for this
+    backend where jax cannot run raises.
   * `AutoBackend`   -- `backend="auto"`: problem-size dispatch between the
     two, numpy below the measured crossover (AUTO_CROSSOVER_*), jax above.
 
@@ -314,24 +315,36 @@ class NumpyBackend(Backend):
 
 # ---------------------------------------------------------------- jax side
 
-_JAX_MODS = None        # (jax, jnp, lax, enable_x64) or an exception
-
-
 def _jax_modules():
-    global _JAX_MODS
-    if _JAX_MODS is None:
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax import lax
-            from jax.experimental import enable_x64
-            _JAX_MODS = (jax, jnp, lax, enable_x64)
-        except Exception as exc:               # pragma: no cover - no jax
-            _JAX_MODS = exc
-    if isinstance(_JAX_MODS, Exception):
-        raise RuntimeError(
-            f"jax backend requested but jax is unavailable: {_JAX_MODS}")
-    return _JAX_MODS
+    """jax is a hard dependency of the jax engine: a missing or broken
+    install raises here instead of degrading to another backend."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    return jax, jnp, lax, jax.enable_x64
+
+
+# <repo>/.jax_cache: a fixed path, because the path is part of the
+# persistent cache's key (a per-run directory would never hit).
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def configure_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; entry points call this
+    before their first compile. Where `JAX_COMPILATION_CACHE_DIR` is set,
+    jax reads it and no other location is set here; otherwise the cache
+    lives in `.jax_cache/` at the repository root. -> the cache directory."""
+    jax = _jax_modules()[0]
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = _DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    # The scheduler's programs compile in about a second each on a TPU,
+    # under jax's default one-second floor for caching a program.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def _pow2(n: int) -> int:
@@ -345,11 +358,19 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
     """Build (once per process and pallas-flag) the jitted kernel programs.
 
     All float work is f64 (callers wrap invocations in `enable_x64`); the
-    Pallas dispatch inside `place` runs f32 scores on real TPUs -- see
-    `repro.kernels.placement` for the precision note."""
+    Pallas kernel inside `place` orders slaves by an exact f32 split of the
+    f64 score -- see `repro.kernels.placement`."""
     if use_pallas in _JAX_FNS:
         return _JAX_FNS[use_pallas]
     jax, jnp, lax, _ = _jax_modules()
+
+    def rounded(prod):
+        """`prod` rounded on its own before it meets an add, as numpy
+        rounds it. XLA's CPU compiler otherwise contracts a product and
+        the next add into one FMA (a single rounding), which breaks ties
+        in the best-fit score differently from numpy. It does not contract
+        through a select, and no product here is NaN."""
+        return jnp.where(jnp.isnan(prod), 0.0, prod)
 
     @jax.jit
     def probe(d, n_max, total):
@@ -384,15 +405,15 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
         q = jnp.maximum(q, 1.0)
         q = jnp.minimum(q, need_f)
         qn = jnp.where(fit, q, 0.0).astype(jnp.int64)
-        score = (free[:, 0] - di[0]) * inv_cap[:, 0]
+        score = rounded((free[:, 0] - di[0]) * inv_cap[:, 0])
         for k in range(1, m):
-            score = score + (free[:, k] - di[k]) * inv_cap[:, k]
+            score = score + rounded((free[:, k] - di[k]) * inv_cap[:, k])
         masked = jnp.where(fit, score, jnp.inf)
         if use_pallas:
             from ..kernels.placement import best_fit_counts
-            counts = best_fit_counts(masked.astype(jnp.float32),
-                                     qn.astype(jnp.int32),
-                                     need_i.astype(jnp.int32))
+            counts = best_fit_counts(masked, qn.astype(jnp.int32),
+                                     need_i.astype(jnp.int32),
+                                     interpret=False)
             return counts.astype(jnp.int64)
         order = jnp.argsort(masked, stable=True)    # ties -> lowest index
         csum = jnp.minimum(jnp.cumsum(qn[order]), need_i)
@@ -428,7 +449,8 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
                              jnp.int64(0))
             need = jnp.maximum(lim - base - prev, 0)
             counts = place_core(free, di, inv_cap, need)
-            free = free - counts[:, None].astype(free.dtype) * di[None, :]
+            free = free - rounded(counts[:, None].astype(free.dtype)
+                                  * di[None, :])
             totals = totals.at[k].set(prev + counts.sum())
             return (free, totals), counts
 
@@ -458,7 +480,7 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
             return r.max(axis=1) / w
 
         n_min_f = n_min.astype(d.dtype)
-        need = n_min_f[:, None] * d                        # zero on pad rows
+        need = rounded(n_min_f[:, None] * d)               # zero on pad rows
         tot_need = need.sum(axis=0)
         all_fit = jnp.all(tot_need <= total + _EPS)
 
@@ -498,7 +520,9 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
         def body(st):
             cnt, rem, alive, _ = st
             d_eff = jnp.where(alive[:, None], d_s, 0.0)
-            cum = jnp.cumsum(d_eff, axis=0)
+            # The parallel-prefix form of cumsum: XLA's TPU compiler takes
+            # minutes over the reduce-window one in f64.
+            cum = lax.associative_scan(jnp.add, d_eff, axis=0)
             ok = jnp.all(cum <= rem[None, :] + _EPS, axis=1)
             bad = alive & ~ok
             any_bad = bad.any()
@@ -529,27 +553,33 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
 class JaxBackend(Backend):
     """jax.jit backend; see the module docstring for the padding contract.
 
-    `use_pallas=None` (default) engages the Pallas placement kernel only on
-    TPU backends (`jax.default_backend() == "tpu"`), mirroring the `auto`
-    impl of `repro.kernels.ops`; the lax composition is the CPU/GPU
-    fallback and the one the f64 bit-exactness guarantee applies to."""
+    The placement inner loop is the compiled Pallas kernel on a TPU and the
+    lax argsort composition on any other platform (`use_pallas=None`).
+    `use_pallas=True` off a TPU raises: the kernel is never interpreted
+    here. `use_pallas` records which of the two runs."""
 
     name = "jax"
 
     def __init__(self, use_pallas: Optional[bool] = None):
         jax, jnp, _, enable_x64 = _jax_modules()
+        on_tpu = jax.default_backend() == "tpu"
         if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
+            use_pallas = on_tpu
+        if use_pallas and not on_tpu:
+            raise RuntimeError(
+                "the Pallas placement kernel needs a TPU; jax's default "
+                f"backend is {jax.default_backend()!r}")
         self.use_pallas = bool(use_pallas)
         self._jax, self._jnp = jax, jnp
         self._x64 = enable_x64
         self._fns = _build_jax_fns(self.use_pallas)
         self.compile_s = 0.0
+        self.compile_s_by_tag: Dict[str, float] = {}
         self._seen: set = set()
 
     # One compile per (kernel, padded shape signature): time the first call
-    # of each and book it under compile_s (the steady-state per-event cost
-    # is what the benchmarks should see).
+    # of each and book it under compile_s and compile_s_by_tag[kernel] (the
+    # steady-state per-event cost is what the benchmarks should see).
     def _run(self, tag: str, *args):
         fn = self._fns[tag]
         key = (tag,) + tuple(
@@ -561,9 +591,22 @@ class JaxBackend(Backend):
             t0 = _time.perf_counter()
             out = fn(*args)
             out = self._jax.block_until_ready(out)
-            self.compile_s += _time.perf_counter() - t0
+            dt = _time.perf_counter() - t0
+            self.compile_s += dt
+            self.compile_s_by_tag[tag] = \
+                self.compile_s_by_tag.get(tag, 0.0) + dt
             self._seen.add(key)
             return out
+
+    def compiled_text(self, tag: str) -> str:
+        """Optimized HLO of kernel `tag` at the first padded shapes it ran
+        with (e.g. to check that `place_run` holds the Pallas kernel)."""
+        jax, jnp = self._jax, self._jnp
+        key = next(k for k in self._seen if k[0] == tag)
+        with self._x64():
+            specs = [jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+                     for shape, dtype in key[1:]]
+            return self._fns[tag].lower(*specs).compile().as_text()
 
     # ---- ops protocol (jnp on host arrays; f64 via the x64 scope)
     def argsort(self, keys):
@@ -713,8 +756,7 @@ class AutoBackend(Backend):
     suite + the bench `timeline_bit_exact_vs_jax` gate), so mixing them
     per kernel call is safe: the placement kernels switch on the SLAVE
     axis (their dominant dimension), the ladder/probe kernels on the app
-    axis. When jax is not importable the dispatcher degrades to pure
-    numpy instead of failing, so REPRO_BACKEND=auto is safe everywhere."""
+    axis."""
 
     name = "auto"
 
@@ -729,10 +771,9 @@ class AutoBackend(Backend):
             if crossover_apps is None else crossover_apps)
         self._np = NumpyBackend()
         self._jax: Optional[JaxBackend] = None
-        self._jax_ok = backend_available("jax")
 
     def _pick(self, size: int, crossover: int) -> Backend:
-        if not self._jax_ok or size < crossover:
+        if size < crossover:
             return self._np
         if self._jax is None:                   # lazy: first large call
             self._jax = JaxBackend()
@@ -799,7 +840,6 @@ def auto_dispatch_report(n_slaves: int, n_apps: int,
     return {
         "placement": be._pick(int(n_slaves), be.crossover_slaves).name,
         "ladder": be._pick(int(n_apps), be.crossover_apps).name,
-        "jax_available": be._jax_ok,
         "crossover_slaves": be.crossover_slaves,
         "crossover_apps": be.crossover_apps,
     }
@@ -821,12 +861,3 @@ def get_backend(name: str) -> Backend:
         raise ValueError(
             f"unknown backend {name!r}; available: {sorted(_BACKENDS)}")
     return cls()
-
-
-def backend_available(name: str) -> bool:
-    if name == "jax":
-        try:
-            _jax_modules()
-        except RuntimeError:
-            return False
-    return name in _BACKENDS

@@ -1,12 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Each op takes the MODEL layout, adapts to the kernel layout, and dispatches:
-  impl="pallas"     -> Pallas kernel (TPU compiled; interpret=True elsewhere)
+  impl="pallas"     -> compiled Pallas kernel (raises off a TPU)
   impl="ref"        -> pure-jnp oracle
   impl="auto"       -> pallas on TPU backends, ref otherwise
 
-The interpret flag is resolved from the default backend so the same model
-code runs on the CPU CI container and on a real TPU pod.
+Interpret mode is never chosen here; the kernel tests ask for it by
+argument on the kernels themselves.
 """
 from __future__ import annotations
 
@@ -27,14 +27,17 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _resolve(impl: str) -> Tuple[bool, bool]:
-    """-> (use_pallas, interpret)."""
+def _use_pallas(impl: str) -> bool:
     if impl == "ref":
-        return False, False
+        return False
     if impl == "pallas":
-        return True, not _on_tpu()
+        if not _on_tpu():
+            raise RuntimeError(
+                "impl='pallas' needs a TPU; jax's default backend is "
+                f"{jax.default_backend()!r}")
+        return True
     if impl == "auto":
-        return (True, False) if _on_tpu() else (False, False)
+        return _on_tpu()
     raise ValueError(impl)
 
 
@@ -48,14 +51,14 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     B, S, Hq, Dh = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
-    use_pallas, interpret = _resolve(impl)
+    use_pallas = _use_pallas(impl)
     qh = jnp.moveaxis(q, 1, 2).reshape(B, Hkv, G, S, Dh)
     kh = jnp.moveaxis(k, 1, 2)
     vh = jnp.moveaxis(v, 1, 2)
     if use_pallas:
         o = flash_attention_gqa(qh, kh, vh, causal=causal, window=window,
                                 logit_softcap=logit_softcap, block_q=block_q,
-                                block_k=block_k, interpret=interpret)
+                                block_k=block_k)
     else:
         o = _ref.attention_ref(qh.reshape(B, Hq, S, Dh), kh, vh,
                                causal=causal, window=window,
@@ -73,8 +76,7 @@ def ssd_scan(xh: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     Returns (y (B,S,H,P), h_final (B,H,P,N))."""
     B, S, H, P = xh.shape
     N = Bm.shape[-1]
-    use_pallas, interpret = _resolve(impl)
-    if not use_pallas:
+    if not _use_pallas(impl):
         return _ref.ssd_ref(xh, dt, A, Bm, Cm)
     assert S % chunk == 0, (S, chunk)
     C = S // chunk
@@ -82,7 +84,7 @@ def ssd_scan(xh: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     dtk = jnp.moveaxis(dt, 2, 1).reshape(B, H, C, chunk)
     Bk = Bm.reshape(B, C, chunk, N)
     Ck = Cm.reshape(B, C, chunk, N)
-    y, h = _ssd_scan(xk, dtk, A, Bk, Ck, interpret=interpret)
+    y, h = _ssd_scan(xk, dtk, A, Bk, Ck)
     y = jnp.moveaxis(y.reshape(B, H, S, P), 1, 2)
     return y, h
 
@@ -91,15 +93,13 @@ def ssd_scan(xh: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
 def grouped_gemm(x: jnp.ndarray, w: jnp.ndarray, *, impl: str = "auto",
                  ) -> jnp.ndarray:
     """x (E, C, D), w (E, D, F) -> (E, C, F)."""
-    use_pallas, interpret = _resolve(impl)
-    if use_pallas:
+    if _use_pallas(impl):
         E, C, D = x.shape
         F = w.shape[-1]
         bm = 128 if C % 128 == 0 else C
         bn = 128 if F % 128 == 0 else F
         bk = 128 if D % 128 == 0 else D
-        return _moe_gemm(x, w, block_m=bm, block_n=bn, block_k=bk,
-                         interpret=interpret)
+        return _moe_gemm(x, w, block_m=bm, block_n=bn, block_k=bk)
     return _ref.moe_gemm_ref(x, w)
 
 
@@ -109,12 +109,10 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, *, eps: float = 1e-6,
     """x (..., D), w (D,)."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
-    use_pallas, interpret = _resolve(impl)
-    if use_pallas:
+    if _use_pallas(impl):
         R = x2.shape[0]
         br = 256 if R % 256 == 0 else (R if R <= 256 else 1)
-        y = _rmsnorm_kernel(x2, w, eps=eps, block_rows=br,
-                            interpret=interpret)
+        y = _rmsnorm_kernel(x2, w, eps=eps, block_rows=br)
     else:
         y = _ref.rmsnorm_ref(x2, w, eps)
     return y.reshape(shape)

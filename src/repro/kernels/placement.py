@@ -24,10 +24,12 @@ Contract (enforced by the caller, `repro.core.backend.JaxBackend`):
   * q int32, pre-clipped to [0, need]; infeasible slaves carry q = 0 (their
     score may be +inf). int32 accumulation then never overflows for
     b * need < 2^31.
-  * score f32 on real TPUs (f64 is unsupported there); the f64 bitwise
-    guarantee applies to the lax fallback, which is what non-TPU backends
-    use. In interpret mode the kernel accepts f64 too, which is how the
-    tests pin it against the oracle exactly.
+  * score f32 or f64. The kernel itself compares f32 only: an f64 score is
+    split outside the kernel into three f32 parts (hi, mid, lo) whose
+    unevaluated sum is the score exactly, and the kernel compares them
+    lexicographically. That order equals the f64 order for every finite
+    score (IEEE f64 needs all three parts; the TPU's f64, a pair of f32,
+    needs two), so two distinct f64 scores never merge into one tie.
 
 `best_fit_counts_ref` is the pure-jnp oracle (the argsort/cumfill
 composition itself).
@@ -46,10 +48,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
+_PARTS = 3
+# Block indices stay int32 under jax_enable_x64 (Mosaic rejects i64 ones).
+_I0 = np.int32(0)
 
-def _placement_kernel(score_j_ref, score_k_ref, q_k_ref, q_j_ref, need_ref,
+
+def _placement_kernel(key_j_ref, key_k_ref, q_k_ref, q_j_ref, need_ref,
                       out_ref, *, block: int):
     k = pl.program_id(1)
     nk = pl.num_programs(1)
@@ -58,57 +65,75 @@ def _placement_kernel(score_j_ref, score_k_ref, q_k_ref, q_j_ref, need_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    sj = score_j_ref[...]                                  # (1, B)
-    sk = score_k_ref[...].reshape(block, 1)                # (B, 1)
+    kj = key_j_ref[...]                                    # (PARTS, B)
+    kk = key_k_ref[...]                                    # (PARTS, B)
     qk = q_k_ref[...].reshape(block, 1)                    # (B, 1)
     jidx = (pl.program_id(0) * block
             + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1))
     kidx = (k * block
             + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0))
-    # (B_k, B_j) strict-predecessor mask, ties broken by slave index.
-    precedes = (sk < sj) | ((sk == sj) & (kidx < jidx))
+    # (B_k, B_j) strict-predecessor mask: lexicographic over the key parts
+    # (most significant first), ties broken by slave index.
+    precedes = kidx < jidx
+    for p in reversed(range(_PARTS)):
+        sj = kj[p:p + 1, :]                                # (1, B)
+        sk = kk[p:p + 1, :].reshape(block, 1)              # (B, 1)
+        precedes = (sk < sj) | ((sk == sj) & precedes)
     out_ref[...] += jnp.sum(
-        jnp.where(precedes, qk, 0), axis=0, dtype=jnp.int32,
+        jnp.where(precedes, qk, jnp.int32(0)), axis=0, dtype=jnp.int32,
     ).reshape(1, block)
 
     @pl.when(k == nk - 1)
     def _epilogue():
         need = need_ref[0, 0]
         before = out_ref[...]
-        out_ref[...] = jnp.clip(need - before, 0, q_j_ref[...])
+        out_ref[...] = jnp.clip(need - before, jnp.int32(0), q_j_ref[...])
+
+
+def split_key(score: jnp.ndarray) -> jnp.ndarray:
+    """(b,) f32/f64 score -> (PARTS, b) f32 parts, most significant first,
+    summing exactly to the score (non-finite scores keep only part 0)."""
+    if score.dtype == jnp.float32:
+        zero = jnp.zeros_like(score)
+        return jnp.stack([score] + [zero] * (_PARTS - 1))
+    finite = jnp.isfinite(score)
+    parts, rest = [], score
+    for _ in range(_PARTS):
+        part = rest.astype(jnp.float32)
+        parts.append(part)
+        rest = jnp.where(finite, rest - part.astype(score.dtype), 0.0)
+    return jnp.stack(parts)
 
 
 def best_fit_counts(score: jnp.ndarray, q: jnp.ndarray, need: jnp.ndarray,
                     *, block: int = 256,
-                    interpret: bool | None = None) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """score (b,), q (b,) int32 in [0, need], need () int32 -> counts (b,).
 
-    `interpret=None` resolves like `repro.kernels.ops`: compiled on TPU,
-    interpreter elsewhere."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    Compiled for the TPU unless the caller passes `interpret=True` (the
+    tests do, to pin the kernel against the oracle on CPU)."""
     b = score.shape[0]
     bb = min(block, b)
     if b % bb:
         raise ValueError(f"slaves {b} must divide block {bb}")
     grid = (b // bb, b // bb)
-    s2 = score.reshape(1, b)
-    q2 = q.reshape(1, b)
-    need2 = need.reshape(1, 1)
+    key = split_key(score)
+    q2 = q.astype(jnp.int32).reshape(1, b)
+    need2 = need.astype(jnp.int32).reshape(1, 1)
     out = pl.pallas_call(
         functools.partial(_placement_kernel, block=bb),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bb), lambda j, k: (0, j)),    # score, j tile
-            pl.BlockSpec((1, bb), lambda j, k: (0, k)),    # score, k tile
-            pl.BlockSpec((1, bb), lambda j, k: (0, k)),    # q, k tile
-            pl.BlockSpec((1, bb), lambda j, k: (0, j)),    # q, j tile
-            pl.BlockSpec((1, 1), lambda j, k: (0, 0)),     # need
+            pl.BlockSpec((_PARTS, bb), lambda j, k: (_I0, j)),   # key, j tile
+            pl.BlockSpec((_PARTS, bb), lambda j, k: (_I0, k)),   # key, k tile
+            pl.BlockSpec((1, bb), lambda j, k: (_I0, k)),        # q, k tile
+            pl.BlockSpec((1, bb), lambda j, k: (_I0, j)),        # q, j tile
+            pl.BlockSpec((1, 1), lambda j, k: (_I0, _I0)),       # need
         ],
-        out_specs=pl.BlockSpec((1, bb), lambda j, k: (0, j)),
+        out_specs=pl.BlockSpec((1, bb), lambda j, k: (_I0, j)),
         out_shape=jax.ShapeDtypeStruct((1, b), jnp.int32),
         interpret=interpret,
-    )(s2, s2, q2, q2, need2)
+    )(key, key, q2, q2, need2)
     return out.reshape(b)
 
 

@@ -77,8 +77,7 @@ class ElasticTrainer:
         """Fresh start on a device group (one data shard per device)."""
         self._build(devices)
         key = jax.random.PRNGKey(self.cfg.seed)
-        with jax.default_device(jax.devices("cpu")[0] if not devices
-                                else devices[0]):
+        with jax.default_device(self.devices[0]):
             state = init_train_state(key, self.cfg.model, self.cfg.optimizer)
         self.state = jax.device_put(state, self._state_sharding(state))
         self.pipeline = TokenPipeline(self.cfg.data,

@@ -33,16 +33,14 @@ import pytest
 from repro.core import (AbsorberConfig, ApplicationSpec, ClusterRuntime,
                         ClusterSpec, Completion, DormMaster, OptimizerConfig,
                         PolicyTimer, Reallocated, RecordingProtocol, Resize,
-                        ResourceVector, Storm, TraceConfig, backend_available,
-                        generate_trace, heterogeneous_cluster)
+                        ResourceVector, Storm, TraceConfig, generate_trace,
+                        heterogeneous_cluster)
 
 try:
     from hypothesis import given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:                                    # pragma: no cover
     HAVE_HYPOTHESIS = False
-
-HAVE_JAX = backend_available("jax")
 
 
 def _master(soa=True, incremental=True, backend="numpy"):
@@ -173,7 +171,6 @@ else:
         _check_absorbed_engines_bit_exact(seed)
 
 
-@pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
 @pytest.mark.parametrize("seed", [2, 11])
 def test_absorbed_floods_bit_exact_vs_jax_backend(seed):
     cluster, wl, resizes = _scenario(seed, quantum=900.0)

@@ -18,23 +18,21 @@ indistinguishable at every level:
     delta/full solve counters.
 
 Runs under hypothesis when available (CI installs it); falls back to a
-seeded-random sweep of the same checks otherwise.  The whole module skips
-cleanly when jax is not importable (bare images)."""
+seeded-random sweep of the same checks otherwise."""
+import os
+
 import numpy as np
 import pytest
 
 from repro.core import (ApplicationSpec, ClusterSpec, DormMaster,
-                        OptimizerConfig, RecordingProtocol, ResourceVector,
-                        backend_available, get_backend)
+                        JaxBackend, OptimizerConfig, RecordingProtocol,
+                        ResourceVector, configure_compile_cache, get_backend)
 
 try:
     from hypothesis import given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:                                    # pragma: no cover
     HAVE_HYPOTHESIS = False
-
-pytestmark = pytest.mark.skipif(not backend_available("jax"),
-                                reason="jax not installed")
 
 # Modest example counts: every distinct padded shape jit-compiles once
 # per process, and the pow2 padding contract keeps that set small.
@@ -241,6 +239,37 @@ def test_jax_backend_books_compile_time():
     m = DormMaster(cluster, "greedy", cfg, protocol=RecordingProtocol())
     for op in ops:
         _apply(m, op)
+    be = m.optimizer.backend
     assert m.backend_compile_s >= 0.0
-    assert m.backend_compile_s == pytest.approx(m.optimizer.backend.compile_s)
+    assert m.backend_compile_s == pytest.approx(be.compile_s)
+    assert sum(be.compile_s_by_tag.values()) == pytest.approx(be.compile_s)
     assert "backend_compile" in m.phase_breakdown()
+
+
+def test_pallas_kernel_needs_a_tpu():
+    """Off a TPU the engine runs the lax path; asking for the Pallas
+    kernel there raises instead of running its interpreter."""
+    assert JaxBackend().use_pallas is False
+    with pytest.raises(RuntimeError, match="TPU"):
+        JaxBackend(use_pallas=True)
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """`JAX_COMPILATION_CACHE_DIR` wins and no other location is set;
+    without it the cache sits in `.jax_cache/` at the repository root."""
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert configure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = configure_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          floor)

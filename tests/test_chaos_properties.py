@@ -31,16 +31,13 @@ from repro.core import (AbsorberConfig, ApplicationSpec, ChaosConfig,
                         OptimizerConfig, Reallocated, RecordingProtocol,
                         Resize, ResourceVector, SlaveDegraded, SlaveDrained,
                         SlaveFailed, SlaveRestored, TraceConfig,
-                        backend_available, generate_trace,
-                        heterogeneous_cluster)
+                        generate_trace, heterogeneous_cluster)
 
 try:
     from hypothesis import given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:                                    # pragma: no cover
     HAVE_HYPOTHESIS = False
-
-HAVE_JAX = backend_available("jax")
 
 THETAS = ((0.2, 0.2), (1.0, 1.0), (0.1, 0.3))
 
@@ -334,7 +331,6 @@ else:
         _check_absorbed_chaos_engines(seed)
 
 
-@pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
 @pytest.mark.parametrize("seed", [3, 17])
 def test_chaos_timelines_bit_exact_vs_jax_backend(seed):
     cluster, wl = _scenario(seed)

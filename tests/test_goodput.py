@@ -16,7 +16,7 @@ from repro.core import (ApplicationSpec, ClusterSimulator, ClusterSpec,
                         DormMaster, GoodputCurve, OptimizerConfig,
                         RecordingProtocol, ReferenceClusterSimulator,
                         ResourceVector, SimResult, TraceConfig, WorkloadApp,
-                        amdahl_curve, anchored_serial_work, backend_available,
+                        amdahl_curve, anchored_serial_work,
                         curve_for_model, derive_curve, generate_trace,
                         heterogeneous_cluster, make_optimizer, paper_testbed,
                         speedup_ratios, work_anchor)
@@ -253,8 +253,6 @@ def test_colgen_objective_weights_columns_by_goodput():
     assert counts["app1"] >= moe.knee(24) or counts["app1"] >= apps[0].n_min
 
 
-@pytest.mark.skipif(not backend_available("jax"),
-                    reason="jax backend not available")
 def test_goodput_greedy_numpy_jax_parity():
     wl = generate_trace(TraceConfig(n_apps=12, seed=5, goodput_curves=True,
                                     serving_fraction=0.0))

@@ -146,6 +146,13 @@ def test_ops_auto_falls_back_to_ref_on_cpu():
     assert float(jnp.abs(o_auto - o_ref).max()) == 0.0
 
 
+def test_ops_pallas_impl_needs_a_tpu():
+    # Off a TPU, impl="pallas" raises; ops never picks interpret mode.
+    x = jnp.ones((4, 128))
+    with pytest.raises(RuntimeError, match="TPU"):
+        ops.rmsnorm(x, jnp.ones(128), impl="pallas")
+
+
 # -------------------------------------------------- placement (scheduler)
 
 PLACE_SIZES = [8, 32, 256, 512]
@@ -154,10 +161,8 @@ PLACE_SIZES = [8, 32, 256, 512]
 @pytest.mark.parametrize("b", PLACE_SIZES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_best_fit_counts_vs_oracle(b, dtype):
-    from jax.experimental import enable_x64
-
     from repro.kernels.placement import best_fit_counts, best_fit_counts_ref
-    with enable_x64():
+    with jax.enable_x64():
         rng = np.random.default_rng(b)
         for trial in range(6):
             score = rng.uniform(0.0, 4.0, size=b)
