@@ -12,9 +12,9 @@ path, exactly like tests/test_replay_xl.py -- or a real log via
     `ClusterRuntime` with the event-storm absorber engaged
     (`AbsorberConfig(window_s=--batch-window-s)`: mixed arrival +
     completion + resize floods coalesce into one policy pass each) and
-    bench_scale-style timing (PolicyTimer medians amortize each absorbed
-    pass over its events; absorbed-event fraction and the batch-size
-    histogram are reported),
+    bench_scale-style timing (PolicyTimer charges every event of an
+    absorbed pass the whole pass; absorbed-event fraction and the
+    batch-size histogram are reported),
   * matched-scale synthetic trace -- the same cluster and scheduler over
     a `generate_trace` workload of the same size, closing the ROADMAP
     gate "replay per-event median within ~2x of the synthetic-trace

@@ -50,6 +50,7 @@ from repro.core import (AbsorberConfig, ApplicationSpec,  # noqa: E402
                         ResourceVector, TraceConfig, configure_compile_cache,
                         generate_trace, heterogeneous_cluster,
                         validate_allocation)
+from repro.core.telemetry import compile_counter  # noqa: E402
 
 # Loss agreement between a trainer resharded across chip groups and the
 # same trainer on one chip: f32 training whose only difference is the
@@ -109,11 +110,14 @@ def scheduler_phase(n_slaves: int = 5000, n_apps: int = 2000,
               f"{len(r['violations'])} invariant violations, "
               f"wall {r['wall_s']:.3f} s (context only)", flush=True)
     be = runs["jax"]["backend"]
+    compiles = compile_counter()
     print(f"jax engine: Pallas placement kernel "
           f"{'on' if be.use_pallas else 'off'}; compile "
-          f"{be.compile_s:.3f} s", flush=True)
-    for tag, s in sorted(be.compile_s_by_tag.items()):
-        print(f"  compile {tag}: {s:.3f} s", flush=True)
+          f"{be.compile_s:.3f} s ({compiles.cache_hits} program(s) loaded "
+          f"from the persistent cache)", flush=True)
+    for name in sorted(compiles.count):
+        print(f"  compile {name}: {compiles.count[name]} program(s), "
+              f"{compiles.seconds[name]:.3f} s", flush=True)
 
     failures = []
     for backend, r in runs.items():
@@ -134,7 +138,7 @@ def scheduler_phase(n_slaves: int = 5000, n_apps: int = 2000,
         failures.append("timelines differ between the jax and numpy engines")
     if first is not None:
         failures.append(f"allocations differ from event {first} on")
-    if "place_run" not in be.compile_s_by_tag:
+    if "dorm.place_run" not in compiles.count:
         failures.append("place_run never ran on the jax engine")
     else:
         has_kernel = "tpu_custom_call" in be.compiled_text("place_run")

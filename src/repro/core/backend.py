@@ -37,10 +37,19 @@ next power of two before dispatch and slices the result back:
     bound are masked to +inf and never granted).
 
 A steady-state cluster therefore compiles each kernel ONCE per padded-shape
-bucket; subsequent events reuse the trace. First-call compilation time is
-accumulated in `Backend.compile_s` so `DormMaster.phase_breakdown()` /
-`PolicyTimer` can report it in a separate `backend_compile` bucket instead
-of polluting per-event medians.
+bucket; subsequent events reuse the trace. jax's own compile events are
+counted per program name (`telemetry.compile_counter()`; a dispatch here
+books its compile as `dorm.<program>`); `Backend.compile_s` reads that
+count, and `DormMaster.phase_breakdown()` / `PolicyTimer` report
+it as `backend_compile`.
+
+Spans
+-----
+`JaxBackend.place_run`, `ladder_counts` and `saturating_probe` record into
+the owner's `telemetry.Spans`: `backend.<program>.prep` (padding and
+schedule arrays), `.dispatch` (the jitted call until it returns), `.wait`
+(the blocking copy of the output to the host, which holds the device time)
+and, for `place_run`, `.apply` (grants written into `x` and `free`).
 
 Exactness
 ---------
@@ -57,10 +66,11 @@ empirically, fractional demands included.
 from __future__ import annotations
 
 import os
-import time as _time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from .telemetry import Spans, compile_counter
 
 _EPS = 1e-9
 
@@ -190,6 +200,9 @@ class Backend:
 
     name: str = "abstract"
     compile_s: float = 0.0       # cumulative jit compile time (jax only)
+
+    def __init__(self, spans: Optional[Spans] = None):
+        self.spans = spans       # the owner's span registry (jax only)
 
     # ---- ops protocol (host-array in, host-array out)
     def argsort(self, keys: np.ndarray) -> np.ndarray:
@@ -374,7 +387,8 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
 
     @jax.jit
     def probe(d, n_max, total):
-        return jnp.all(n_max @ d <= total + _EPS)
+        with jax.named_scope("dorm.probe"):
+            return jnp.all(n_max @ d <= total + _EPS)
 
     def place_core(free, di, inv_cap, need_i):
         """-> dense (b,) int64 grant counts (0 on non-granted slaves).
@@ -422,7 +436,8 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
 
     @jax.jit
     def place(free, di, inv_cap, need):
-        return place_core(free, di, inv_cap, need.astype(jnp.int64))
+        with jax.named_scope("dorm.place"):
+            return place_core(free, di, inv_cap, need.astype(jnp.int64))
 
     @jax.jit
     def place_run(free0, inv_cap, d_items, lims, bases, aslots):
@@ -454,14 +469,14 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
             totals = totals.at[k].set(prev + counts.sum())
             return (free, totals), counts
 
-        totals0 = jnp.zeros(K, jnp.int64)
-        ks = jnp.arange(K, dtype=jnp.int64)
-        (_, _), grants = lax.scan(
-            body, (free0, totals0), (d_items, lims, bases, aslots, ks))
+        with jax.named_scope("dorm.place_run"):
+            totals0 = jnp.zeros(K, jnp.int64)
+            ks = jnp.arange(K, dtype=jnp.int64)
+            (_, _), grants = lax.scan(
+                body, (free0, totals0), (d_items, lims, bases, aslots, ks))
         return grants
 
-    @jax.jit
-    def ladder(d, n_min, n_max, w, valid, total, levels):
+    def ladder_fill(d, n_min, n_max, w, valid, total, levels):
         """Vectorized weighted-DRF ladder fill, masked instead of compacted.
 
         numpy compacts the ladder (drops granted/retired entries); here the
@@ -545,6 +560,11 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
         cnt_f, _, _, _ = lax.while_loop(lambda st: ~st[3], body, init)
         return cnt_f
 
+    @jax.jit
+    def ladder(d, n_min, n_max, w, valid, total, levels):
+        with jax.named_scope("dorm.ladder"):
+            return ladder_fill(d, n_min, n_max, w, valid, total, levels)
+
     _JAX_FNS[use_pallas] = {"probe": probe, "place": place,
                             "place_run": place_run, "ladder": ladder}
     return _JAX_FNS[use_pallas]
@@ -560,7 +580,8 @@ class JaxBackend(Backend):
 
     name = "jax"
 
-    def __init__(self, use_pallas: Optional[bool] = None):
+    def __init__(self, use_pallas: Optional[bool] = None,
+                 spans: Optional[Spans] = None):
         jax, jnp, _, enable_x64 = _jax_modules()
         on_tpu = jax.default_backend() == "tpu"
         if use_pallas is None:
@@ -573,39 +594,40 @@ class JaxBackend(Backend):
         self._jax, self._jnp = jax, jnp
         self._x64 = enable_x64
         self._fns = _build_jax_fns(self.use_pallas)
-        self.compile_s = 0.0
-        self.compile_s_by_tag: Dict[str, float] = {}
-        self._seen: set = set()
+        self.spans = spans if spans is not None else Spans()
+        self._compiles = compile_counter()
+        # tag -> [(shape, dtype)] of the first call: what `compiled_text`
+        # lowers again.
+        self._shapes: Dict[str, List[Tuple[tuple, str]]] = {}
 
-    # One compile per (kernel, padded shape signature): time the first call
-    # of each and book it under compile_s and compile_s_by_tag[kernel] (the
-    # steady-state per-event cost is what the benchmarks should see).
+    @property
+    def compile_s(self) -> float:
+        """Seconds jax spent compiling (or loading from its persistent
+        cache) this engine's programs, booked as `dorm.<program>`, in this
+        process; the jit caches are process-wide, so is this count."""
+        sec = self._compiles.seconds
+        return sum(sec.get("dorm." + tag, 0.0) for tag in self._fns)
+
     def _run(self, tag: str, *args):
-        fn = self._fns[tag]
-        key = (tag,) + tuple(
-            (a.shape, str(a.dtype)) if hasattr(a, "shape") else type(a)
-            for a in args)
-        with self._x64():
-            if key in self._seen:
-                return fn(*args)
-            t0 = _time.perf_counter()
-            out = fn(*args)
-            out = self._jax.block_until_ready(out)
-            dt = _time.perf_counter() - t0
-            self.compile_s += dt
-            self.compile_s_by_tag[tag] = \
-                self.compile_s_by_tag.get(tag, 0.0) + dt
-            self._seen.add(key)
-            return out
+        """Dispatch program `tag` (f64 on); returns without waiting. A
+        compile on the way is counted as `dorm.<tag>`."""
+        if tag not in self._shapes:
+            self._shapes[tag] = [(a.shape, str(a.dtype)) for a in args]
+        owner = self._compiles.owner
+        owner.name = "dorm." + tag
+        try:
+            with self._x64():
+                return self._fns[tag](*args)
+        finally:
+            owner.name = None
 
     def compiled_text(self, tag: str) -> str:
         """Optimized HLO of kernel `tag` at the first padded shapes it ran
         with (e.g. to check that `place_run` holds the Pallas kernel)."""
         jax, jnp = self._jax, self._jnp
-        key = next(k for k in self._seen if k[0] == tag)
         with self._x64():
             specs = [jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
-                     for shape, dtype in key[1:]]
+                     for shape, dtype in self._shapes[tag]]
             return self._fns[tag].lower(*specs).compile().as_text()
 
     # ---- ops protocol (jnp on host arrays; f64 via the x64 scope)
@@ -641,33 +663,41 @@ class JaxBackend(Backend):
 
     # ---- scheduler kernels (padded dispatch)
     def saturating_probe(self, d, n_max, total) -> bool:
-        n, m = d.shape
-        n_pad = _pow2(n)
-        d_p = np.zeros((n_pad, m), np.float64)
-        d_p[:n] = d
-        nm_p = np.zeros(n_pad, np.float64)
-        nm_p[:n] = n_max
-        return bool(self._run("probe", d_p, nm_p,
-                              total.astype(np.float64)))
+        sp = self.spans
+        with sp.span("backend.saturating_probe.prep"):
+            n, m = d.shape
+            n_pad = _pow2(n)
+            d_p = np.zeros((n_pad, m), np.float64)
+            d_p[:n] = d
+            nm_p = np.zeros(n_pad, np.float64)
+            nm_p[:n] = n_max
+        with sp.span("backend.saturating_probe.dispatch"):
+            out = self._run("probe", d_p, nm_p, total.astype(np.float64))
+        with sp.span("backend.saturating_probe.wait"):
+            return bool(out)
 
     def ladder_counts(self, d, n_min, n_max, weight, total):
-        n, m = d.shape
-        n_pad = _pow2(n)
-        L = _pow2(int(n_max.max()) if n else 1)
-        d_p = np.zeros((n_pad, m), np.float64)
-        d_p[:n] = d
-        nmin_p = np.zeros(n_pad, np.int64)
-        nmin_p[:n] = n_min
-        nmax_p = np.zeros(n_pad, np.int64)
-        nmax_p[:n] = n_max
-        w_p = np.ones(n_pad, np.float64)
-        w_p[:n] = weight
-        valid = np.zeros(n_pad, bool)
-        valid[:n] = True
-        levels = np.arange(L, dtype=np.int64)
-        out = self._run("ladder", d_p, nmin_p, nmax_p, w_p, valid,
-                        total.astype(np.float64), levels)
-        return np.asarray(out)[:n]
+        sp = self.spans
+        with sp.span("backend.ladder_counts.prep"):
+            n, m = d.shape
+            n_pad = _pow2(n)
+            L = _pow2(int(n_max.max()) if n else 1)
+            d_p = np.zeros((n_pad, m), np.float64)
+            d_p[:n] = d
+            nmin_p = np.zeros(n_pad, np.int64)
+            nmin_p[:n] = n_min
+            nmax_p = np.zeros(n_pad, np.int64)
+            nmax_p[:n] = n_max
+            w_p = np.ones(n_pad, np.float64)
+            w_p[:n] = weight
+            valid = np.zeros(n_pad, bool)
+            valid[:n] = True
+            levels = np.arange(L, dtype=np.int64)
+        with sp.span("backend.ladder_counts.dispatch"):
+            out = self._run("ladder", d_p, nmin_p, nmax_p, w_p, valid,
+                            total.astype(np.float64), levels)
+        with sp.span("backend.ladder_counts.wait"):
+            return np.asarray(out)[:n]
 
     def place_counts(self, free, di, inv_cap, need):
         b, m = free.shape
@@ -698,41 +728,48 @@ class JaxBackend(Backend):
         K = len(items)
         if K == 0:
             return []
-        b, m = free.shape
-        # Tight pow2 (floor 1), NOT `_pow2`: its floor-8 bucket is right for
-        # vectorized app axes, but the scan pays per STEP, so padding a
-        # K=1 flood to 8 steps would octuple the device work. Worst case
-        # this costs log2 extra one-time compiles (K_pad 1, 2, 4, ...).
-        K_pad = 1 << (K - 1).bit_length()
-        f_p, ic_p = self._pad_slaves(free, inv_cap)
-        idx = np.fromiter((i for i, _ in items), np.int64, K)
-        d_items = np.zeros((K_pad, m), np.float64)
-        d_items[:K] = d[idx]
-        lims = np.zeros(K_pad, np.int64)
-        lims[:K] = np.fromiter((lim for _, lim in items), np.int64, K)
-        bases = np.zeros(K_pad, np.int64)
-        bases[:K] = x[idx].sum(axis=1)
-        aslots = np.full(K_pad, -1, np.int64)
-        last: Dict[int, int] = {}
-        for k, i in enumerate(idx.tolist()):
-            j = last.get(i)
-            if j is not None:
-                aslots[k] = j
-            last[i] = k
-        grants = np.asarray(self._run("place_run", f_p, ic_p, d_items,
-                                      lims, bases, aslots))[:K, :b]
-        out: List[int] = []
-        for k in range(K):
-            counts = grants[k]
-            js = np.flatnonzero(counts)
-            if js.size:
-                i = int(idx[k])
-                cj = counts[js]
-                x[i, js] += cj
-                free[js] -= cj[:, None].astype(np.float64) * d[i][None, :]
-                out.append(int(cj.sum()))
-            else:
-                out.append(0)
+        sp = self.spans
+        with sp.span("backend.place_run.prep"):
+            b, m = free.shape
+            # Tight pow2 (floor 1), NOT `_pow2`: its floor-8 bucket is right
+            # for vectorized app axes, but the scan pays per STEP, so
+            # padding a K=1 flood to 8 steps would octuple the device work.
+            # Worst case this costs log2 extra one-time compiles (K_pad 1,
+            # 2, 4, ...).
+            K_pad = 1 << (K - 1).bit_length()
+            f_p, ic_p = self._pad_slaves(free, inv_cap)
+            idx = np.fromiter((i for i, _ in items), np.int64, K)
+            d_items = np.zeros((K_pad, m), np.float64)
+            d_items[:K] = d[idx]
+            lims = np.zeros(K_pad, np.int64)
+            lims[:K] = np.fromiter((lim for _, lim in items), np.int64, K)
+            bases = np.zeros(K_pad, np.int64)
+            bases[:K] = x[idx].sum(axis=1)
+            aslots = np.full(K_pad, -1, np.int64)
+            last: Dict[int, int] = {}
+            for k, i in enumerate(idx.tolist()):
+                j = last.get(i)
+                if j is not None:
+                    aslots[k] = j
+                last[i] = k
+        with sp.span("backend.place_run.dispatch"):
+            dev = self._run("place_run", f_p, ic_p, d_items, lims, bases,
+                            aslots)
+        with sp.span("backend.place_run.wait"):
+            grants = np.asarray(dev)[:K, :b]
+        with sp.span("backend.place_run.apply"):
+            out: List[int] = []
+            for k in range(K):
+                counts = grants[k]
+                js = np.flatnonzero(counts)
+                if js.size:
+                    i = int(idx[k])
+                    cj = counts[js]
+                    x[i, js] += cj
+                    free[js] -= cj[:, None].astype(np.float64) * d[i][None, :]
+                    out.append(int(cj.sum()))
+                else:
+                    out.append(0)
         return out
 
 
@@ -761,7 +798,9 @@ class AutoBackend(Backend):
     name = "auto"
 
     def __init__(self, crossover_slaves: Optional[int] = None,
-                 crossover_apps: Optional[int] = None):
+                 crossover_apps: Optional[int] = None,
+                 spans: Optional[Spans] = None):
+        super().__init__(spans)
         self.crossover_slaves = int(
             os.environ.get("REPRO_AUTO_CROSSOVER_SLAVES",
                            AUTO_CROSSOVER_SLAVES)
@@ -776,17 +815,12 @@ class AutoBackend(Backend):
         if size < crossover:
             return self._np
         if self._jax is None:                   # lazy: first large call
-            self._jax = JaxBackend()
+            self._jax = JaxBackend(spans=self.spans)
         return self._jax
 
     @property
     def compile_s(self) -> float:
         return self._jax.compile_s if self._jax is not None else 0.0
-
-    @compile_s.setter
-    def compile_s(self, value: float) -> None:
-        if self._jax is not None:
-            self._jax.compile_s = value
 
     # ---- ops protocol: host ops stay on numpy (never the bottleneck)
     def argsort(self, keys):
@@ -852,12 +886,12 @@ def auto_dispatch_report(n_slaves: int, n_apps: int,
 _BACKENDS = {"numpy": NumpyBackend, "jax": JaxBackend, "auto": AutoBackend}
 
 
-def get_backend(name: str) -> Backend:
-    """-> a fresh backend instance (each optimizer owns its compile_s
-    accounting; the underlying jit caches are process-global either way)."""
+def get_backend(name: str, spans: Optional[Spans] = None) -> Backend:
+    """-> a fresh backend instance that records its spans in `spans` (the
+    jit caches and their compile count are process-global)."""
     try:
         cls = _BACKENDS[name]
     except KeyError:
         raise ValueError(
             f"unknown backend {name!r}; available: {sorted(_BACKENDS)}")
-    return cls()
+    return cls(spans=spans)

@@ -27,7 +27,6 @@ Two bookkeeping engines behind the same API (`OptimizerConfig.soa`):
 from __future__ import annotations
 
 import dataclasses
-import time as _time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -42,6 +41,7 @@ from .runtime import (ChaosEvent, ReallocationResult, SlaveDegraded,
                       SlaveRestored)
 from .slave import DormSlave
 from .state import ClusterState, LazyAppViews, LazySlaveViews
+from .telemetry import Spans
 from .types import Allocation, ApplicationSpec, ClusterSpec, validate_allocation
 
 _EPS = 1e-9
@@ -66,9 +66,12 @@ class DormMaster:
         self._slave_scale = np.ones(cluster.b)
         self._slave_pos: Dict[str, int] = {
             s: j for j, s in enumerate(self.slave_ids)}
+        # Spans of the master, its optimizer and its backend (see
+        # `phase_s` and `phase_breakdown`).
+        self.spans = Spans()
         # "milp" (exact), "greedy" (heuristic), or "auto" (MILP below
         # cfg.auto_switch_vars variables, greedy above -- the scale path).
-        self.optimizer = make_optimizer(optimizer_kind, cfg)
+        self.optimizer = make_optimizer(optimizer_kind, cfg, self.spans)
         self.protocol: AdjustmentProtocol = protocol or RecordingProtocol()
         self.specs: Dict[str, ApplicationSpec] = {}      # running + pending
         self.pending: List[str] = []                     # admitted, not placed
@@ -81,10 +84,6 @@ class DormMaster:
         self._goodput_on = False
         self.prev_alloc: Optional[Allocation] = None
         self.checkpoints: Dict[str, CheckpointHandle] = {}
-        # Per-phase wall time (solve vs enforce vs metrics; the optimizer
-        # tracks the DRF-refill share of solve) -- see `phase_breakdown`.
-        self.phase_s: Dict[str, float] = {
-            "solve": 0.0, "enforce": 0.0, "metrics": 0.0, "absorb": 0.0}
         if self._soa:
             self.state: Optional[ClusterState] = ClusterState(cluster)
             self.slaves = LazySlaveViews(self.state)
@@ -223,72 +222,71 @@ class DormMaster:
         Returns `(displaced, parked)`: displaced maps app_id -> container
         count AFTER eviction (0 = lost everything) in eviction order;
         parked lists the apps returned to pending (fully evicted)."""
-        t0 = _time.perf_counter()
-        self._slave_scale[j] = factor
-        from .chaos import scale_cluster
-        new_cluster = scale_cluster(self._base_cluster, self._slave_scale)
-        new_cap_row = new_cluster.capacity_matrix()[j].astype(np.float64)
-        displaced: Dict[str, int] = {}
-        parked: List[str] = []
-        if self.state is not None:
-            st = self.state
-            used_row = st.cap[j] - st.free[j]
-            if (used_row > new_cap_row + _EPS).any():
-                for app_id in reversed([a for a in self.specs
-                                        if st.is_placed(a)]):
-                    i = st.row_of[app_id]
-                    cij = int(st.x[i, j])
-                    if cij == 0:
-                        continue
-                    used_row = used_row - cij * st.demand[i]
-                    remaining = int(st.counts[i]) - cij
-                    displaced[app_id] = remaining
-                    if remaining > 0:
-                        row = st.x[i].copy()
-                        row[j] = 0
-                        st.place(app_id, row)
-                    else:
-                        self._park(app_id)
-                        parked.append(app_id)
-                    if not (used_row > new_cap_row + _EPS).any():
-                        break
-            st.set_cluster(new_cluster)
-        else:
-            sid = self.slave_ids[j]
-            slave = self.slaves[sid]
-            used_row = slave.used()
-            if (used_row > new_cap_row + _EPS).any():
-                for app_id in reversed([a for a in self.specs
-                                        if a in self.partitions]):
-                    part = self.partitions[app_id]
-                    victims = [c for c in part.containers
-                               if c.slave_id == sid]
-                    if not victims:
-                        continue
-                    d = self.specs[app_id].demand.as_array()
-                    used_row = used_row - len(victims) * d
-                    remaining = part.n_containers - len(victims)
-                    displaced[app_id] = remaining
-                    if remaining > 0:
-                        for c in victims:
-                            slave.destroy_container(c.container_id)
-                            part.containers.remove(c)
-                        self._placements[app_id][j] = 0
-                    else:
-                        self._park(app_id)
-                        parked.append(app_id)
-                    if not (used_row > new_cap_row + _EPS).any():
-                        break
-            # Swap the slave's spec so used()/available() report against
-            # the post-failure capacity.
-            slave.spec = new_cluster.slaves[j]
-        self.cluster = new_cluster
-        # Re-anchor stickiness: the recovery solve diffs against the
-        # POST-eviction placements, so re-placing a displaced app counts
-        # against the Eq-16 budget while untouched apps stay free to keep.
-        if self.prev_alloc is not None:
-            self.prev_alloc = self._current_allocation()
-        self.phase_s["enforce"] += _time.perf_counter() - t0
+        with self.spans.span("master.enforce"):
+            self._slave_scale[j] = factor
+            from .chaos import scale_cluster
+            new_cluster = scale_cluster(self._base_cluster, self._slave_scale)
+            new_cap_row = new_cluster.capacity_matrix()[j].astype(np.float64)
+            displaced: Dict[str, int] = {}
+            parked: List[str] = []
+            if self.state is not None:
+                st = self.state
+                used_row = st.cap[j] - st.free[j]
+                if (used_row > new_cap_row + _EPS).any():
+                    for app_id in reversed([a for a in self.specs
+                                            if st.is_placed(a)]):
+                        i = st.row_of[app_id]
+                        cij = int(st.x[i, j])
+                        if cij == 0:
+                            continue
+                        used_row = used_row - cij * st.demand[i]
+                        remaining = int(st.counts[i]) - cij
+                        displaced[app_id] = remaining
+                        if remaining > 0:
+                            row = st.x[i].copy()
+                            row[j] = 0
+                            st.place(app_id, row)
+                        else:
+                            self._park(app_id)
+                            parked.append(app_id)
+                        if not (used_row > new_cap_row + _EPS).any():
+                            break
+                st.set_cluster(new_cluster)
+            else:
+                sid = self.slave_ids[j]
+                slave = self.slaves[sid]
+                used_row = slave.used()
+                if (used_row > new_cap_row + _EPS).any():
+                    for app_id in reversed([a for a in self.specs
+                                            if a in self.partitions]):
+                        part = self.partitions[app_id]
+                        victims = [c for c in part.containers
+                                   if c.slave_id == sid]
+                        if not victims:
+                            continue
+                        d = self.specs[app_id].demand.as_array()
+                        used_row = used_row - len(victims) * d
+                        remaining = part.n_containers - len(victims)
+                        displaced[app_id] = remaining
+                        if remaining > 0:
+                            for c in victims:
+                                slave.destroy_container(c.container_id)
+                                part.containers.remove(c)
+                            self._placements[app_id][j] = 0
+                        else:
+                            self._park(app_id)
+                            parked.append(app_id)
+                        if not (used_row > new_cap_row + _EPS).any():
+                            break
+                # Swap the slave's spec so used()/available() report against
+                # the post-failure capacity.
+                slave.spec = new_cluster.slaves[j]
+            self.cluster = new_cluster
+            # Re-anchor stickiness: the recovery solve diffs against the
+            # POST-eviction placements, so re-placing a displaced app counts
+            # against the Eq-16 budget while untouched apps stay free to keep.
+            if self.prev_alloc is not None:
+                self.prev_alloc = self._current_allocation()
         return displaced, parked
 
     def _park(self, app_id: str) -> None:
@@ -411,72 +409,71 @@ class DormMaster:
             dd, pp = self._apply_slave_scale(j, factor)
             displaced.update(dd)          # latest count wins, order kept
             parked.extend(pp)
-        t0 = _time.perf_counter()
-        comp_set = set(completions)
-        cancelled = {s.app_id for s in arrivals} & comp_set
-        arrivals = [s for s in arrivals if s.app_id not in cancelled]
-        # -- completions: one folded free-capacity update.
-        for app_id in completions:
-            if app_id in cancelled:
-                continue
-            if app_id in self.partitions and app_id in self.specs:
-                self.protocol.kill(self.specs[app_id])
-            self._teardown(app_id)
-            self.specs.pop(app_id, None)
-            self._curved.pop(app_id, None)
-            if self.state is not None and app_id in self.state:
-                self.state.forget(app_id)
-            if app_id in self.pending:
-                self.pending.remove(app_id)
-        drop = comp_set - cancelled
-        if drop and self.prev_alloc is not None \
-                and drop & set(self.prev_alloc.app_ids):
-            keep = [i for i, a in enumerate(self.prev_alloc.app_ids)
-                    if a not in drop]
-            self.prev_alloc = Allocation.trusted(
-                tuple(self.prev_alloc.app_ids[i] for i in keep),
-                self.prev_alloc.x[keep])
-        # -- resizes: last-wins per app, dead targets dropped.
-        merged: Dict[str, Tuple[Optional[int], Optional[int]]] = {}
-        for app_id, n_min, n_max in resizes:
-            if app_id in self.specs:
-                merged[app_id] = (n_min, n_max)
-        reverts: List[ApplicationSpec] = []      # tightened old specs
-        tightening = False
-        for app_id, (n_min, n_max) in merged.items():
-            spec = self.specs[app_id]
-            new = spec.with_bounds(n_min=n_min, n_max=n_max)
-            if new.n_min == spec.n_min and new.n_max == spec.n_max:
-                continue
-            if (new.n_min > spec.n_min
-                    or new.n_max < self.containers_of(app_id)):
-                tightening = True
-                reverts.append(spec)
-            self.specs[app_id] = new
-            if self.state is not None:
-                self.state.rebound(new)
-        # -- arrivals: submit_batch's rollback-safe admission.
-        seen = set()
-        for spec in arrivals:
-            if spec.app_id in self.specs or spec.app_id in seen:
-                raise ValueError(f"duplicate app_id {spec.app_id}")
-            seen.add(spec.app_id)
-        if self.state is not None and arrivals:
-            admitted: List[str] = []
-            try:
-                for spec in arrivals:
-                    self.state.admit(spec)
-                    admitted.append(spec.app_id)
-            except Exception:
-                for app_id in admitted:
+        with self.spans.span("master.absorb"):
+            comp_set = set(completions)
+            cancelled = {s.app_id for s in arrivals} & comp_set
+            arrivals = [s for s in arrivals if s.app_id not in cancelled]
+            # -- completions: one folded free-capacity update.
+            for app_id in completions:
+                if app_id in cancelled:
+                    continue
+                if app_id in self.partitions and app_id in self.specs:
+                    self.protocol.kill(self.specs[app_id])
+                self._teardown(app_id)
+                self.specs.pop(app_id, None)
+                self._curved.pop(app_id, None)
+                if self.state is not None and app_id in self.state:
                     self.state.forget(app_id)
-                raise
-        for spec in arrivals:
-            self.specs[spec.app_id] = spec
-            self.pending.append(spec.app_id)
-            if spec.goodput is not None:
-                self._curved[spec.app_id] = spec.goodput
-        self.phase_s["absorb"] += _time.perf_counter() - t0
+                if app_id in self.pending:
+                    self.pending.remove(app_id)
+            drop = comp_set - cancelled
+            if drop and self.prev_alloc is not None \
+                    and drop & set(self.prev_alloc.app_ids):
+                keep = [i for i, a in enumerate(self.prev_alloc.app_ids)
+                        if a not in drop]
+                self.prev_alloc = Allocation.trusted(
+                    tuple(self.prev_alloc.app_ids[i] for i in keep),
+                    self.prev_alloc.x[keep])
+            # -- resizes: last-wins per app, dead targets dropped.
+            merged: Dict[str, Tuple[Optional[int], Optional[int]]] = {}
+            for app_id, n_min, n_max in resizes:
+                if app_id in self.specs:
+                    merged[app_id] = (n_min, n_max)
+            reverts: List[ApplicationSpec] = []      # tightened old specs
+            tightening = False
+            for app_id, (n_min, n_max) in merged.items():
+                spec = self.specs[app_id]
+                new = spec.with_bounds(n_min=n_min, n_max=n_max)
+                if new.n_min == spec.n_min and new.n_max == spec.n_max:
+                    continue
+                if (new.n_min > spec.n_min
+                        or new.n_max < self.containers_of(app_id)):
+                    tightening = True
+                    reverts.append(spec)
+                self.specs[app_id] = new
+                if self.state is not None:
+                    self.state.rebound(new)
+            # -- arrivals: submit_batch's rollback-safe admission.
+            seen = set()
+            for spec in arrivals:
+                if spec.app_id in self.specs or spec.app_id in seen:
+                    raise ValueError(f"duplicate app_id {spec.app_id}")
+                seen.add(spec.app_id)
+            if self.state is not None and arrivals:
+                admitted: List[str] = []
+                try:
+                    for spec in arrivals:
+                        self.state.admit(spec)
+                        admitted.append(spec.app_id)
+                except Exception:
+                    for app_id in admitted:
+                        self.state.forget(app_id)
+                    raise
+            for spec in arrivals:
+                self.specs[spec.app_id] = spec
+                self.pending.append(spec.app_id)
+                if spec.goodput is not None:
+                    self._curved[spec.app_id] = spec.goodput
         # -- ONE solve for the whole flood.
         res = self.reallocate(
             reject_infeasible=tightening or bool(displaced))
@@ -484,13 +481,12 @@ class DormMaster:
             # Group-reject the tightening resizes, park displaced apps the
             # eviction left below n_min, and solve once more with the
             # keep-allocations fallback (always returns a result).
-            t1 = _time.perf_counter()
-            for spec in reverts:
-                self.specs[spec.app_id] = spec
-                if self.state is not None:
-                    self.state.rebound(spec)
-            parked.extend(self._park_below_min(displaced))
-            self.phase_s["absorb"] += _time.perf_counter() - t1
+            with self.spans.span("master.absorb"):
+                for spec in reverts:
+                    self.specs[spec.app_id] = spec
+                    if self.state is not None:
+                        self.state.rebound(spec)
+                parked.extend(self._park_below_min(displaced))
             res = self.reallocate()
         return self._chaos_result(res, displaced, parked)
 
@@ -562,31 +558,40 @@ class DormMaster:
 
     @property
     def backend_compile_s(self) -> float:
-        """Cumulative jit-compile seconds of the optimizer's array backend
-        (0.0 for the numpy backend). First-event compilation is a one-off
-        warm-up, so `phase_breakdown` and `PolicyTimer` book it in its own
-        `backend_compile` bucket instead of the per-event solve time."""
+        """Seconds jax spent compiling the optimizer backend's programs in
+        this process (`telemetry.compile_counter()`; 0.0 for the numpy
+        backend)."""
         be = getattr(self.optimizer, "backend", None)
         return float(be.compile_s) if be is not None else 0.0
 
+    @property
+    def phase_s(self) -> Dict[str, float]:
+        """Cumulative wall seconds of the master's four phases: the
+        optimizer's solve, enforcement, Eq-1/2/4 metrics and the absorber's
+        flood merging (the `master.*` spans)."""
+        total = self.spans.total_s
+        return {p: total.get("master." + p, 0.0)
+                for p in ("solve", "enforce", "metrics", "absorb")}
+
     def phase_breakdown(self) -> Dict[str, float]:
         """Cumulative per-phase scheduling seconds: optimizer solve (split
-        into the DRF-refill share, the column-generation pricing share, the
-        backend jit-compile share and the rest), enforcement (container
-        create/destroy + protocol calls), Eq-1/2/4 metric evaluation, and
-        the absorber's flood-merge bookkeeping (`absorb`)."""
+        into the DRF-refill share, the column-generation pricing share and
+        the rest, device time and any compile inside it included),
+        enforcement (container create/destroy + protocol calls), Eq-1/2/4
+        metric evaluation, the absorber's flood-merge bookkeeping
+        (`absorb`), and the backend's jit compiles (`backend_compile`,
+        also counted inside whichever phase triggered them)."""
         refill = float(getattr(self.optimizer, "refill_s", 0.0))
         pricing = float(getattr(self.optimizer, "pricing_s", 0.0))
-        compile_s = self.backend_compile_s
+        phase = self.phase_s
         return {
             "drf_refill": refill,
             "colgen_pricing": pricing,
-            "backend_compile": compile_s,
-            "solve": max(self.phase_s["solve"] - refill - pricing
-                         - compile_s, 0.0),
-            "enforce": self.phase_s["enforce"],
-            "metrics": self.phase_s["metrics"],
-            "absorb": self.phase_s["absorb"],
+            "backend_compile": self.backend_compile_s,
+            "solve": phase["solve"] - refill - pricing,
+            "enforce": phase["enforce"],
+            "metrics": phase["metrics"],
+            "absorb": phase["absorb"],
         }
 
     # --------------------------------------------------------- reallocation
@@ -599,10 +604,9 @@ class DormMaster:
         result when the solve is infeasible (the resize path reverts the
         triggering bound change in that case)."""
         apps = list(self.specs.values())
-        t0 = _time.perf_counter()
-        alloc = self.optimizer.solve(apps, self.cluster, self.prev_alloc,
-                                     state=self.state)
-        self.phase_s["solve"] += _time.perf_counter() - t0
+        with self.spans.span("master.solve"):
+            alloc = self.optimizer.solve(apps, self.cluster, self.prev_alloc,
+                                         state=self.state)
         if alloc is None:
             if reject_infeasible:
                 return None
@@ -637,45 +641,44 @@ class DormMaster:
         containers: create containers -> configure executors/schedulers ->
         start.
         """
-        t0 = _time.perf_counter()
-        adjusted: List[str] = []
-        started: List[str] = []
-        counts_changed: Dict[str, int] = {}
-        spec_of = {a.app_id: a for a in apps}
+        with self.spans.span("master.enforce"):
+            adjusted: List[str] = []
+            started: List[str] = []
+            counts_changed: Dict[str, int] = {}
+            spec_of = {a.app_id: a for a in apps}
 
-        if self.state is not None:
-            to_place = self._changed_soa(alloc)
-        else:
-            to_place = self._changed_legacy(alloc)
-
-        # Phase 1 (Fig 5, step 3): save + kill + destroy containers of every
-        # running app whose placement changed -- frees capacity first, so
-        # phase-2 creations never race the teardowns.
-        for app_id, _, was_running in to_place:
-            if was_running:
-                spec = spec_of[app_id]
-                self.checkpoints[app_id] = self.protocol.save_state(spec)
-                self.protocol.kill(spec)
-                self._teardown(app_id)
-
-        # Phase 2 (Fig 5, step 4): create containers, configure executors and
-        # schedulers, resume adjusted apps / start new ones.
-        for app_id, new_row, was_running in to_place:
-            spec = spec_of[app_id]
-            self._place(spec, new_row)
-            n_new = int(new_row.sum())
-            counts_changed[app_id] = n_new
-            if was_running:
-                self.protocol.resume(spec, n_new,
-                                     self.checkpoints.get(app_id))
-                adjusted.append(app_id)
+            if self.state is not None:
+                to_place = self._changed_soa(alloc)
             else:
-                self.protocol.start(spec, n_new)
-                started.append(app_id)
-                if app_id in self.pending:
-                    self.pending.remove(app_id)
+                to_place = self._changed_legacy(alloc)
 
-        self.phase_s["enforce"] += _time.perf_counter() - t0
+            # Phase 1 (Fig 5, step 3): save + kill + destroy containers of
+            # every running app whose placement changed -- frees capacity
+            # first, so phase-2 creations never race the teardowns.
+            for app_id, _, was_running in to_place:
+                if was_running:
+                    spec = spec_of[app_id]
+                    self.checkpoints[app_id] = self.protocol.save_state(spec)
+                    self.protocol.kill(spec)
+                    self._teardown(app_id)
+
+            # Phase 2 (Fig 5, step 4): create containers, configure
+            # executors and schedulers, resume adjusted apps / start new
+            # ones.
+            for app_id, new_row, was_running in to_place:
+                spec = spec_of[app_id]
+                self._place(spec, new_row)
+                n_new = int(new_row.sum())
+                counts_changed[app_id] = n_new
+                if was_running:
+                    self.protocol.resume(spec, n_new,
+                                         self.checkpoints.get(app_id))
+                    adjusted.append(app_id)
+                else:
+                    self.protocol.start(spec, n_new)
+                    started.append(app_id)
+                    if app_id in self.pending:
+                        self.pending.remove(app_id)
         result = self._result(alloc, tuple(adjusted), tuple(started),
                               tuple(self.pending),
                               counts_changed=counts_changed,
@@ -792,81 +795,84 @@ class DormMaster:
                 started: Tuple[str, ...], pending: Tuple[str, ...],
                 counts_changed: Optional[Dict[str, int]] = None,
                 trusted_shares: bool = False) -> ReallocationResult:
-        t0 = _time.perf_counter()
-        if alloc.app_ids == tuple(self.specs):
-            keep = None
-            apps = list(self.specs.values())
-            sub = alloc
-        else:
-            keep = [i for i, a in enumerate(alloc.app_ids) if a in self.specs]
-            apps = [self.specs[alloc.app_ids[i]] for i in keep]
-            sub = Allocation.trusted(tuple(alloc.app_ids[i] for i in keep),
-                                     alloc.x[keep] if keep
-                                     else np.zeros((0, self.cluster.b),
-                                                   np.int64))
-        d = totals = None
-        if self.state is not None and apps:
-            idx = self.state.rows_for([a.app_id for a in apps])
-            d = self.state.demand[idx]
-            # After enforcement the state rows ARE this allocation, so the
-            # maintained per-app counts equal sub.x.sum(axis=1).
-            totals = self.state.counts[idx]
-        if self.state is not None:
-            # Eq 4 evaluated by construction: every adjusted app changed its
-            # row (and only those), summed over A^t ∩ A^{t-1}.
-            overhead = len(adjusted)
-        else:
-            overhead = resource_adjustment_overhead(self.prev_alloc, sub)
-        shares_vec = getattr(self.optimizer, "last_shares_vec", None)
-        if trusted_shares and totals is not None and shares_vec is not None \
-                and len(shares_vec) == len(apps):
-            # Eq 2 fully in arrays: actual dominant shares from the
-            # maintained counts vs the solver's s_hat vector (same app
-            # order as this result, by the trusted-shares contract).
-            actual_vec = _shares_vec(totals, d, self.cluster.total_capacity())
-            loss = float(np.abs(actual_vec - shares_vec).sum())
-        else:
-            # Reuse the optimizer's DRF targets for Eq 2 when they cover
-            # exactly this app set (true for every feasible solve): the
-            # fairness metric then costs O(n*m) instead of a second
-            # progressive-filling pass.
-            shares = getattr(self.optimizer, "last_shares", None)
-            if not trusted_shares and shares is not None \
-                    and set(shares) != {a.app_id for a in apps}:
-                shares = None
-            loss = cluster_fairness_loss(sub, apps, self.cluster,
-                                         theoretical=shares,
-                                         d=d, totals=totals)
-        # Instantaneous cluster goodput Σ gp_i(N_i) in container-equivalents
-        # (gp_i(N) = N for uncurved apps). Only computed when some admitted
-        # app carries a curve; every other workload keeps the 0.0 default.
-        goodput = 0.0
-        if self._curved:
-            self._goodput_on = True
-        if self._goodput_on:
-            cnts = totals if totals is not None else sub.x.sum(axis=1)
-            goodput = float(cnts.sum())
-            for i, a in enumerate(apps):
-                curve = self._curved.get(a.app_id)
-                if curve is not None:
-                    n_i = int(cnts[i])
-                    goodput += curve.at(n_i) - float(n_i)
-        result = ReallocationResult(
-            allocation=sub,
-            adjusted_app_ids=adjusted,
-            started_app_ids=started,
-            pending_app_ids=pending,
-            utilization=resource_utilization(sub, apps, self.cluster,
-                                             d=d, totals=totals),
-            fairness_loss=loss,
-            # Eq 4 evaluated literally: r_i = 1 iff any x_{i,j} changed vs
-            # the previous allocation, summed over A^t ∩ A^{t-1}.
-            adjustment_overhead=overhead,
-            changed_counts=counts_changed,
-            # Certified gap of the solve (colgen LP bound / monolithic MILP
-            # dual bound); None when the path proves nothing.
-            optimality_gap=getattr(self.optimizer, "last_gap", None),
-            goodput=goodput,
-        )
-        self.phase_s["metrics"] += _time.perf_counter() - t0
+        with self.spans.span("master.metrics"):
+            if alloc.app_ids == tuple(self.specs):
+                keep = None
+                apps = list(self.specs.values())
+                sub = alloc
+            else:
+                keep = [i for i, a in enumerate(alloc.app_ids)
+                        if a in self.specs]
+                apps = [self.specs[alloc.app_ids[i]] for i in keep]
+                sub = Allocation.trusted(tuple(alloc.app_ids[i] for i in keep),
+                                         alloc.x[keep] if keep
+                                         else np.zeros((0, self.cluster.b),
+                                                       np.int64))
+            d = totals = None
+            if self.state is not None and apps:
+                idx = self.state.rows_for([a.app_id for a in apps])
+                d = self.state.demand[idx]
+                # After enforcement the state rows ARE this allocation, so the
+                # maintained per-app counts equal sub.x.sum(axis=1).
+                totals = self.state.counts[idx]
+            if self.state is not None:
+                # Eq 4 evaluated by construction: every adjusted app changed
+                # its row (and only those), summed over A^t ∩ A^{t-1}.
+                overhead = len(adjusted)
+            else:
+                overhead = resource_adjustment_overhead(self.prev_alloc, sub)
+            shares_vec = getattr(self.optimizer, "last_shares_vec", None)
+            if trusted_shares and totals is not None \
+                    and shares_vec is not None \
+                    and len(shares_vec) == len(apps):
+                # Eq 2 fully in arrays: actual dominant shares from the
+                # maintained counts vs the solver's s_hat vector (same app
+                # order as this result, by the trusted-shares contract).
+                actual_vec = _shares_vec(totals, d,
+                                         self.cluster.total_capacity())
+                loss = float(np.abs(actual_vec - shares_vec).sum())
+            else:
+                # Reuse the optimizer's DRF targets for Eq 2 when they cover
+                # exactly this app set (true for every feasible solve): the
+                # fairness metric then costs O(n*m) instead of a second
+                # progressive-filling pass.
+                shares = getattr(self.optimizer, "last_shares", None)
+                if not trusted_shares and shares is not None \
+                        and set(shares) != {a.app_id for a in apps}:
+                    shares = None
+                loss = cluster_fairness_loss(sub, apps, self.cluster,
+                                             theoretical=shares,
+                                             d=d, totals=totals)
+            # Instantaneous cluster goodput Σ gp_i(N_i) in
+            # container-equivalents
+            # (gp_i(N) = N for uncurved apps). Only computed when some admitted
+            # app carries a curve; every other workload keeps the 0.0 default.
+            goodput = 0.0
+            if self._curved:
+                self._goodput_on = True
+            if self._goodput_on:
+                cnts = totals if totals is not None else sub.x.sum(axis=1)
+                goodput = float(cnts.sum())
+                for i, a in enumerate(apps):
+                    curve = self._curved.get(a.app_id)
+                    if curve is not None:
+                        n_i = int(cnts[i])
+                        goodput += curve.at(n_i) - float(n_i)
+            result = ReallocationResult(
+                allocation=sub,
+                adjusted_app_ids=adjusted,
+                started_app_ids=started,
+                pending_app_ids=pending,
+                utilization=resource_utilization(sub, apps, self.cluster,
+                                                 d=d, totals=totals),
+                fairness_loss=loss,
+                # Eq 4 evaluated literally: r_i = 1 iff any x_{i,j} changed vs
+                # the previous allocation, summed over A^t ∩ A^{t-1}.
+                adjustment_overhead=overhead,
+                changed_counts=counts_changed,
+                # Certified gap of the solve (colgen LP bound / monolithic MILP
+                # dual bound); None when the path proves nothing.
+                optimality_gap=getattr(self.optimizer, "last_gap", None),
+                goodput=goodput,
+            )
         return result

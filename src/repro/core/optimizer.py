@@ -47,7 +47,6 @@ from __future__ import annotations
 import dataclasses
 import math
 import os
-import time as _time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -59,6 +58,7 @@ from .backend import NumpyBackend, _place_counts_np, get_backend
 # of the configured device backend (it is the master's state-backed hot
 # path that the device fusion targets).
 _HOST_BACKEND = NumpyBackend()
+from .telemetry import Spans
 from .drf import (IncrementalDRF, drf_container_counts,
                   drf_container_counts_reference, drf_shares)
 from .types import (Allocation, ApplicationSpec, ClusterSpec, demand_matrix,
@@ -265,15 +265,15 @@ def _drf_targets(apps: Sequence[ApplicationSpec], cluster: ClusterSpec,
 class MilpOptimizer:
     """Exact P2 via scipy.optimize.milp (HiGHS)."""
 
-    def __init__(self, cfg: OptimizerConfig = OptimizerConfig()):
+    def __init__(self, cfg: OptimizerConfig = OptimizerConfig(),
+                 spans: Optional[Spans] = None):
         if not _HAVE_SCIPY:  # pragma: no cover
             raise RuntimeError("scipy not available; use GreedyOptimizer")
         self.cfg = cfg
+        self.spans = spans if spans is not None else Spans()
         self.last_shares: Optional[Dict[str, float]] = None
         self.last_shares_vec: Optional[np.ndarray] = None  # solve app order
         self.last_changed: Optional[Tuple[str, ...]] = None  # never proven
-        self.refill_s = 0.0        # cumulative DRF-refill time (phase stat)
-        self.pricing_s = 0.0       # cumulative colgen pricing time
         self.monolithic_solves = 0
         self.rolling_solves = 0
         self.colgen_solves = 0
@@ -287,6 +287,17 @@ class MilpOptimizer:
         self.last_gap: Optional[float] = None
         self.last_bound: Optional[float] = None
         self.last_objective: Optional[float] = None
+
+    @property
+    def refill_s(self) -> float:
+        """Cumulative DRF-refill seconds (the `optimizer.refill` span)."""
+        return self.spans.total_s.get("optimizer.refill", 0.0)
+
+    @property
+    def pricing_s(self) -> float:
+        """Cumulative column-generation pricing seconds (the
+        `optimizer.pricing` span)."""
+        return self.spans.total_s.get("optimizer.pricing", 0.0)
 
     # ------------------------------------------------------ dense assembly
 
@@ -498,9 +509,8 @@ class MilpOptimizer:
             self.last_objective = 0.0
             return Allocation.empty((), cluster.b)
         app_ids = tuple(a.app_id for a in apps)
-        t_refill = _time.perf_counter()
-        drf_counts, s_hat_vec = _drf_targets(apps, cluster)
-        self.refill_s += _time.perf_counter() - t_refill
+        with self.spans.span("optimizer.refill"):
+            drf_counts, s_hat_vec = _drf_targets(apps, cluster)
         self.last_shares = dict(zip(app_ids, map(float, s_hat_vec)))
         self.last_shares_vec = s_hat_vec
         if self.cfg.column_generation:
@@ -954,64 +964,64 @@ class MilpOptimizer:
             pi_cap, pi_f = y_ub[:n_cap], float(y_ub[n_cap])
             pi_r = float(y_ub[n_cap + 1]) if n_r else 0.0
 
-            # -- pricing (timed: the phase breakdown's colgen_pricing).
-            t0 = _time.perf_counter()
-            if use_gp:
-                # Goodput objective: -w_i gp_i(N) is convex piecewise
-                # linear with a breakpoint at EVERY integer, so the
-                # 5-candidate closed form below is no longer the exact
-                # minimizer -- price over the full level range instead
-                # (same enumeration the pool enrichment uses; exactness is
-                # what keeps the Lagrangian bound rigorous).
-                cap_slope = -(cap_mask * d[:, cap_k].T
-                              * pi_cap[:, None]).sum(axis=0)
-                lv = nmax_v - nmin_v + 1
-                starts = np.cumsum(lv) - lv
-                l_app = np.repeat(np.arange(n), lv)
-                l_n = nmin_v[l_app] \
-                    + (np.arange(int(lv.sum())) - starts[l_app])
-                rc_l = (-util_w[l_app] * gp_tab[l_app, l_n]
-                        + cap_slope[l_app] * l_n
-                        - pi_f * np.abs(g[l_app] * l_n - s_hat_vec[l_app])
-                        - pi_r * ((prev_n[l_app] >= 0)
-                                  & (l_n != prev_n[l_app]))
-                        - sigma[l_app])
-                best_n = np.empty(n, np.int64)
-                min_rc = np.empty(n)
-                for i in range(n):
-                    sl = rc_l[starts[i]: starts[i] + lv[i]]
-                    k = int(np.argmin(sl))
-                    min_rc[i] = sl[k]
-                    best_n[i] = int(nmin_v[i]) + k
-            else:
-                a_lin = -util_w - (cap_mask * d[:, cap_k].T
-                                   * pi_cap[:, None]).sum(axis=0)  # slope in N
-                with np.errstate(divide="ignore", invalid="ignore"):
-                    bp = np.where(g > 0, s_hat_vec / np.maximum(g, 1e-300),
-                                  nmin_v.astype(np.float64))
-                # pre-clip keeps floor/ceil inside int64 range for tiny g
-                bp = np.clip(bp, 0.0, nmax_v.astype(np.float64) + 1.0)
-                cand = np.stack([
-                    nmin_v, nmax_v,
-                    np.floor(bp).astype(np.int64),
-                    np.ceil(bp).astype(np.int64),
-                    np.where(prev_n >= 0, prev_n, nmin_v)], axis=1)
-                cand = np.clip(cand, nmin_v[:, None], nmax_v[:, None])
-                loss_c = np.abs(g[:, None] * cand - s_hat_vec[:, None])
-                chg_c = (prev_n[:, None] >= 0) & (cand != prev_n[:, None])
-                rc = (a_lin[:, None] * cand - pi_f * loss_c
-                      - pi_r * chg_c - sigma[:, None])
-                best = np.argmin(rc, axis=1)
-                min_rc = rc[np.arange(n), best]
-                best_n = cand[np.arange(n), best]
-            # Lagrangian bound: z_LP >= z_RMP + sum_i min(0, min_rc_i)
-            # (each convexity block contributes exactly one unit of weight;
-            # the candidate set provably contains the true minimizer).
-            bound = -(z_rmp + float(np.minimum(min_rc, 0.0).sum()))
-            util_bound = bound if util_bound is None \
-                else min(util_bound, bound)
-            improving = np.flatnonzero(min_rc < -1e-7)
-            self.pricing_s += _time.perf_counter() - t0
+            # -- pricing (the phase breakdown's colgen_pricing).
+            with self.spans.span("optimizer.pricing"):
+                if use_gp:
+                    # Goodput objective: -w_i gp_i(N) is convex piecewise
+                    # linear with a breakpoint at EVERY integer, so the
+                    # 5-candidate closed form below is no longer the exact
+                    # minimizer -- price over the full level range instead
+                    # (same enumeration the pool enrichment uses; exactness is
+                    # what keeps the Lagrangian bound rigorous).
+                    cap_slope = -(cap_mask * d[:, cap_k].T
+                                  * pi_cap[:, None]).sum(axis=0)
+                    lv = nmax_v - nmin_v + 1
+                    starts = np.cumsum(lv) - lv
+                    l_app = np.repeat(np.arange(n), lv)
+                    l_n = nmin_v[l_app] \
+                        + (np.arange(int(lv.sum())) - starts[l_app])
+                    rc_l = (-util_w[l_app] * gp_tab[l_app, l_n]
+                            + cap_slope[l_app] * l_n
+                            - pi_f * np.abs(g[l_app] * l_n - s_hat_vec[l_app])
+                            - pi_r * ((prev_n[l_app] >= 0)
+                                      & (l_n != prev_n[l_app]))
+                            - sigma[l_app])
+                    best_n = np.empty(n, np.int64)
+                    min_rc = np.empty(n)
+                    for i in range(n):
+                        sl = rc_l[starts[i]: starts[i] + lv[i]]
+                        k = int(np.argmin(sl))
+                        min_rc[i] = sl[k]
+                        best_n[i] = int(nmin_v[i]) + k
+                else:
+                    # a_lin: the slope in N.
+                    a_lin = -util_w - (cap_mask * d[:, cap_k].T
+                                       * pi_cap[:, None]).sum(axis=0)
+                    with np.errstate(divide="ignore", invalid="ignore"):
+                        bp = np.where(g > 0, s_hat_vec / np.maximum(g, 1e-300),
+                                      nmin_v.astype(np.float64))
+                    # pre-clip keeps floor/ceil inside int64 range for tiny g
+                    bp = np.clip(bp, 0.0, nmax_v.astype(np.float64) + 1.0)
+                    cand = np.stack([
+                        nmin_v, nmax_v,
+                        np.floor(bp).astype(np.int64),
+                        np.ceil(bp).astype(np.int64),
+                        np.where(prev_n >= 0, prev_n, nmin_v)], axis=1)
+                    cand = np.clip(cand, nmin_v[:, None], nmax_v[:, None])
+                    loss_c = np.abs(g[:, None] * cand - s_hat_vec[:, None])
+                    chg_c = (prev_n[:, None] >= 0) & (cand != prev_n[:, None])
+                    rc = (a_lin[:, None] * cand - pi_f * loss_c
+                          - pi_r * chg_c - sigma[:, None])
+                    best = np.argmin(rc, axis=1)
+                    min_rc = rc[np.arange(n), best]
+                    best_n = cand[np.arange(n), best]
+                # Lagrangian bound: z_LP >= z_RMP + sum_i min(0, min_rc_i)
+                # (each convexity block contributes exactly one unit of weight;
+                # the candidate set provably contains the true minimizer).
+                bound = -(z_rmp + float(np.minimum(min_rc, 0.0).sum()))
+                util_bound = bound if util_bound is None \
+                    else min(util_bound, bound)
+                improving = np.flatnonzero(min_rc < -1e-7)
             if not improving.size:
                 # Converged: `bound` (with its tiny within-tolerance
                 # Lagrangian correction) is already the rigorous value.
@@ -1455,13 +1465,17 @@ class GreedyOptimizer:
     `delta_solves` / `full_solves` count which path answered.
     """
 
-    def __init__(self, cfg: OptimizerConfig = OptimizerConfig()):
+    def __init__(self, cfg: OptimizerConfig = OptimizerConfig(),
+                 spans: Optional[Spans] = None):
         self.cfg = cfg
         self.drf = IncrementalDRF()
+        # The owner's span registry (a DormMaster hands over its own),
+        # shared with the backend.
+        self.spans = spans if spans is not None else Spans()
         # Array backend for the hot kernels (core.backend); "numpy" is the
         # bit-exactness reference, "jax" the jit/lax port. `compile_s` on it
         # feeds the master's `backend_compile` phase bucket.
-        self.backend = get_backend(cfg.backend)
+        self.backend = get_backend(cfg.backend, self.spans)
         self._last_shares: Optional[Dict[str, float]] = None
         self._last_share_ids: Optional[Tuple[str, ...]] = None
         self.last_shares_vec: Optional[np.ndarray] = None  # solve app order
@@ -1472,7 +1486,6 @@ class GreedyOptimizer:
         self.last_changed: Optional[Tuple[str, ...]] = None
         self.delta_solves = 0
         self.full_solves = 0
-        self.refill_s = 0.0        # cumulative DRF-refill time (phase stat)
         # Futile top-up memo: app_id -> (state.epoch, target) of a delta
         # placement attempt that could not reach its target. Free capacity
         # only shrinks while the epoch is unchanged, so the retry is
@@ -1481,6 +1494,11 @@ class GreedyOptimizer:
         # and this bounds the dict at O(live apps) over unbounded streams.
         self._futile: Dict[str, Tuple[int, int]] = {}
         self._futile_epoch = -1
+
+    @property
+    def refill_s(self) -> float:
+        """Cumulative DRF-refill seconds (the `optimizer.refill` span)."""
+        return self.spans.total_s.get("optimizer.refill", 0.0)
 
     @property
     def last_shares(self) -> Optional[Dict[str, float]]:
@@ -1507,460 +1525,474 @@ class GreedyOptimizer:
         (the DormMaster's SoA engine) -- per-app coefficient arrays and the
         incrementally-maintained free/aggregate vectors are then reused
         instead of being rebuilt from the spec objects every event."""
+        with self.spans.span("optimizer.solve"):
+            return self._solve(apps, cluster, prev, _targets, state)
+
+    def _solve(self, apps, cluster, prev, _targets, state,
+               ) -> Optional[Allocation]:
         self.last_changed = None
         if not apps:
             self.last_shares = {}
             self.last_shares_vec = np.zeros(0)
             self.last_changed = ()
             return Allocation.empty((), cluster.b)
-        soa = self.cfg.soa
-        n, b, m = len(apps), cluster.b, cluster.m
-        app_ids = tuple(a.app_id for a in apps)
-        if state is not None:
-            idx = state.rows_for(app_ids)
-            d = state.demand[idx]
-            g = state.g[idx]
-            util_w = state.util_w[idx]
-            nmin_v = state.n_min[idx]
-            nmax_v = state.n_max[idx]
-            integral = state.all_integral()
-        else:
-            d = demand_matrix(apps)
-            g = _dominant_coeff(apps, cluster, d)
-            util_w = _util_coeff(apps, cluster, d)
-            nmin_v = np.fromiter((a.n_min for a in apps), np.int64, n)
-            nmax_v = np.fromiter((a.n_max for a in apps), np.int64, n)
-            integral = bool((d == np.floor(d)).all())
-        cap = cluster.capacity_matrix().astype(np.float64)
-        total_cap = cluster.total_capacity()
-        budget_l = fairness_budget(self.cfg, m)
-
-        # Goodput knee-capping (cfg.goodput_aware): apps with a non-linear
-        # speedup curve are targeted at their knee instead of n_max --
-        # containers past it buy < goodput_knee of a container's progress
-        # and are better spent on apps still on the steep part. The cap is
-        # an effective-BOUNDS shrink applied before the DRF refill, so the
-        # shares, the utilization push and the placement all see the same
-        # (capped) problem and Eq-15's budget stays self-consistent. With
-        # no curved apps (_knee_caps -> None; every seed workload) nothing
-        # changes and the solve is bit-identical. Skipped when the caller
-        # supplies `_targets`: MILP warm starts own the problem definition
-        # (the exact paths keep P2's count-linear objective).
-        apps_fill: Sequence[ApplicationSpec] = apps
-        if self.cfg.goodput_aware and _targets is None:
-            kc = _knee_caps(apps, nmin_v, nmax_v, self.cfg.goodput_knee)
-            if kc is not None:
-                nmax_v = kc
-                apps_fill = [
-                    a if a.n_max <= int(kc[i])
-                    else a.with_bounds(n_max=int(kc[i]))
-                    for i, a in enumerate(apps)]
-
-        # -- DRF refill (timed: the phase breakdown's drf_refill bucket).
-        t_refill = _time.perf_counter()
-        fast = False
-        if _targets is not None:
-            drf_counts, s_hat_vec = _targets
-            self.last_shares = dict(zip(app_ids, map(float, s_hat_vec)))
-            target = np.fromiter((drf_counts[a] for a in app_ids),
-                                 np.int64, n)
-        elif self.cfg.incremental:
+        with self.spans.span("optimizer.gather"):
+            soa = self.cfg.soa
+            n, b, m = len(apps), cluster.b, cluster.m
+            app_ids = tuple(a.app_id for a in apps)
             if state is not None:
-                if integral:
-                    # O(m) probe against the incrementally-maintained
-                    # aggregate n_max demand (exact for integral demands)
-                    # instead of the O(n*m) re-aggregation in
-                    # `drf.saturating_counts`.
-                    fast = state.saturates_at_nmax()
-                else:
-                    # Fractional demands: the running aggregate is not
-                    # ulp-exact, so probe against a fresh aggregation
-                    # (same arithmetic as `drf.saturating_counts`, on the
-                    # state's SoA arrays via the backend seam).
-                    fast = self.backend.saturating_probe(
-                        d, nmax_v.astype(np.float64), total_cap)
-                if fast:
-                    self.drf.fast_hits += 1
-                    target = nmax_v.astype(np.int64, copy=True)
-                    s_hat_vec = _shares_vec(target, d, total_cap)
-                    self._last_shares = None          # built lazily
-                    self._last_share_ids = app_ids
-                else:
-                    # Full ladder refill straight on the SoA arrays (the
-                    # backend seam: numpy = the reference fill, jax = the
-                    # jitted ladder program); shares follow in one
-                    # vectorized pass, dict built lazily.
-                    self.drf.full_refills += 1
-                    target = self.backend.ladder_counts(
-                        d, nmin_v, nmax_v,
-                        state.weight[idx].astype(np.float64), total_cap)
-                    s_hat_vec = _shares_vec(target, d, total_cap)
-                    self._last_shares = None          # built lazily
-                    self._last_share_ids = app_ids
+                idx = state.rows_for(app_ids)
+                d = state.demand[idx]
+                g = state.g[idx]
+                util_w = state.util_w[idx]
+                nmin_v = state.n_min[idx]
+                nmax_v = state.n_max[idx]
+                integral = state.all_integral()
             else:
-                # Incremental DRF refill: O(n*m) saturating fast path when
-                # it provably matches the full filling, full otherwise.
-                drf_counts, shares, fast = self.drf.targets(
-                    apps_fill, cluster, reference=not soa)
-                self.last_shares = shares
-                s_hat_vec = np.fromiter((shares[a] for a in app_ids),
-                                        np.float64, n)
+                d = demand_matrix(apps)
+                g = _dominant_coeff(apps, cluster, d)
+                util_w = _util_coeff(apps, cluster, d)
+                nmin_v = np.fromiter((a.n_min for a in apps), np.int64, n)
+                nmax_v = np.fromiter((a.n_max for a in apps), np.int64, n)
+                integral = bool((d == np.floor(d)).all())
+            cap = cluster.capacity_matrix().astype(np.float64)
+            total_cap = cluster.total_capacity()
+            budget_l = fairness_budget(self.cfg, m)
+
+            # Goodput knee-capping (cfg.goodput_aware): apps with a non-linear
+            # speedup curve are targeted at their knee instead of n_max --
+            # containers past it buy < goodput_knee of a container's progress
+            # and are better spent on apps still on the steep part. The cap is
+            # an effective-BOUNDS shrink applied before the DRF refill, so the
+            # shares, the utilization push and the placement all see the same
+            # (capped) problem and Eq-15's budget stays self-consistent. With
+            # no curved apps (_knee_caps -> None; every seed workload) nothing
+            # changes and the solve is bit-identical. Skipped when the caller
+            # supplies `_targets`: MILP warm starts own the problem definition
+            # (the exact paths keep P2's count-linear objective).
+            apps_fill: Sequence[ApplicationSpec] = apps
+            if self.cfg.goodput_aware and _targets is None:
+                kc = _knee_caps(apps, nmin_v, nmax_v, self.cfg.goodput_knee)
+                if kc is not None:
+                    nmax_v = kc
+                    apps_fill = [
+                        a if a.n_max <= int(kc[i])
+                        else a.with_bounds(n_max=int(kc[i]))
+                        for i, a in enumerate(apps)]
+
+        with self.spans.span("optimizer.refill"):
+            # -- DRF refill (the phase breakdown's drf_refill bucket).
+            fast = False
+            if _targets is not None:
+                drf_counts, s_hat_vec = _targets
+                self.last_shares = dict(zip(app_ids, map(float, s_hat_vec)))
                 target = np.fromiter((drf_counts[a] for a in app_ids),
                                      np.int64, n)
-        else:
-            # Full re-solve semantics (the seed's per-event behaviour):
-            # progressive filling from scratch on every event.
-            drf_counts, s_hat_vec = _drf_targets(apps_fill, cluster,
-                                                 reference=not soa, d=d)
-            self.last_shares = dict(zip(app_ids, map(float, s_hat_vec)))
-            target = np.fromiter((drf_counts[a] for a in app_ids),
-                                 np.int64, n)
-        self.refill_s += _time.perf_counter() - t_refill
-        self.last_shares_vec = s_hat_vec
-
-        # -- step 1: choose target counts.
-        if np.any(target < nmin_v):
-            # Aggregate capacity cannot host every app's minimum -> infeasible;
-            # paper behaviour: keep existing allocations (master handles it).
-            return None
-
-        def total_loss(counts: np.ndarray) -> float:
-            return float(np.abs(g * counts - s_hat_vec).sum())
-
-        drf_target0 = target       # pre-push DRF point (step-3 re-check)
-
-        # The master appends new apps after surviving ones, so prev's app
-        # list is almost always a prefix of the current one; membership is
-        # then just an index compare and NO prev dict is built at all.
-        # Otherwise: row views, not copies (as_dict copies every row; this
-        # runs per event and the solver only reads previous rows).
-        n_prev = len(prev.app_ids) if prev is not None else 0
-        k_prefix = 0
-        prev_map: Optional[Dict[str, np.ndarray]] = None
-        if soa and n_prev and prev.app_ids == app_ids[:n_prev]:
-            k_prefix = n_prev
-        elif prev is not None:
-            prev_map = dict(zip(prev.app_ids, prev.x))
-        else:
-            prev_map = {}
-
-        def in_prev(i: int) -> bool:
-            return i < k_prefix if prev_map is None \
-                else app_ids[i] in prev_map
-
-        def prev_row(i: int) -> np.ndarray:
-            return prev.x[i] if prev_map is None else prev_map[app_ids[i]]
-
-        delta = bool(self.cfg.incremental and fast and n_prev
-                     and (prev_map is None
-                          or set(prev_map).issubset(app_ids)))
-        if delta:
-            # Guard: a shrunk bound (Resize event) can push a target below
-            # the previous count; the stickiness loop must then TRIM rows,
-            # so the prev-rows warm start would not match -- full path.
-            if state is not None:
-                if bool((state.counts[idx] > target).any()):
-                    delta = False
-            elif prev_map is None:
-                if bool((prev.x.sum(axis=1) > target[:k_prefix]).any()):
-                    delta = False
-            else:
-                tgt_of = dict(zip(app_ids, target.tolist()))
-                if any(int(row.sum()) > tgt_of[a]
-                       for a, row in prev_map.items()):
-                    delta = False
-        if delta and not integral and not soa:
-            # Legacy-engine guard: with fractional demands (e.g. Philly
-            # n_cpus/n_gpus or Alibaba plan_cpu/100 replays) the delta
-            # path's one-matmul free computation and the legacy full path's
-            # sequential row subtraction can differ in the last ulp and
-            # flip a near-tied best-fit argmin. The SoA engine closes that
-            # hole by CANONICALIZING free on both paths (one
-            # cap - x^T d matmul, order-independent -- see the warm-start
-            # block below), so fractional replays take the delta path
-            # there; the legacy engine stays the frozen reference.
-            delta = False
-
-        if not fast:
-            # Greedy utilization push above the DRF point within the Eq-15
-            # budget (skipped on the fast path: every target already sits at
-            # n_max, so the push is provably a no-op). Pure-python
-            # incremental loop: the loss delta of one extra container is
-            # local to the app, so the Eq-15 re-check is O(1), not O(n).
-            remaining = (total_cap - target @ d).tolist()
-            d_list = d.tolist()
-            g_list = g.tolist()
-            s_hat_list = s_hat_vec.tolist()
-            tgt = target.tolist()
-            nmax_list = nmax_v.tolist()
-            cur_loss = sum(abs(g_list[i] * tgt[i] - s_hat_list[i])
-                           for i in range(n))
-            order = np.argsort(-util_w).tolist()  # best utilization first
-            rng_m = range(m)
-            improved = True
-            while improved:
-                improved = False
-                for i in order:
-                    if tgt[i] >= nmax_list[i]:
-                        continue
-                    di = d_list[i]
-                    if any(di[k] > remaining[k] + 1e-9 for k in rng_m):
-                        continue
-                    old_li = abs(g_list[i] * tgt[i] - s_hat_list[i])
-                    new_li = abs(g_list[i] * (tgt[i] + 1) - s_hat_list[i])
-                    if cur_loss - old_li + new_li <= budget_l + 1e-9:
-                        tgt[i] += 1
-                        cur_loss += new_li - old_li
-                        for k in rng_m:
-                            remaining[k] -= di[k]
-                        improved = True
-            target = np.array(tgt, dtype=np.int64)
-
-        # -- step 2: placement with stickiness. The backend seam covers the
-        # SoA state-backed solves (the master's hot path); spec-only solves
-        # (MILP warm starts, standalone calls) keep the host scatter.
-        if not soa:
-            place_fn = _best_fit_place
-            place_be = None
-        else:
-            # The whole two-pass placement schedule is executed by ONE
-            # backend call (`Backend.place_run`): numpy runs the reference
-            # sequential loop, jax fuses the schedule into a single device
-            # program. Spec-only SoA solves stay on the host backend.
-            place_fn = None
-            place_be = self.backend if state is not None else _HOST_BACKEND
-        inv_cap = 1.0 / np.maximum(cap, 1e-9)
-        changed_track: Optional[set] = None   # indices changed vs prev rows
-        if delta:
-            # Delta warm start: every surviving app keeps its previous row
-            # verbatim (the stickiness loop below would reproduce exactly
-            # that: targets are at n_max >= previous counts, and previous
-            # rows are jointly capacity-feasible, so nothing is trimmed).
-            self.delta_solves += 1
-            # Only the SoA placement loops feed the tracker; the legacy
-            # engine must fall back to the row compare.
-            changed_track = set() if soa else None
-            if state is not None:
-                # The state's rows ARE the previous allocation: one gather
-                # for x, one copy of the incrementally-maintained free
-                # matrix -- no per-app row loop, no (b, n) @ (n, m) matmul.
-                x = state.x[idx]                # fancy index -> fresh copy
-                if integral:
-                    free = state.free.copy()
-                else:
-                    # Fractional demands: derive free canonically from x
-                    # (one order-independent matmul). The full path below
-                    # canonicalizes its free the same way after the
-                    # stickiness loop, so both paths feed the best-fit
-                    # scatter bit-identical scores -- for integral demands
-                    # the incrementally-maintained matrix already IS that
-                    # value exactly, and the copy is cheaper.
-                    free = cap - x.T.astype(np.float64) @ d
-                sums = state.counts[idx].copy()
-            else:
-                x = np.zeros((n, b), dtype=np.int64)
-                if k_prefix:
-                    x[:k_prefix] = prev.x       # one bulk copy
-                else:
-                    for i, a in enumerate(app_ids):
-                        pr = prev_map.get(a)
-                        if pr is not None:
-                            x[i] = pr
-                free = cap - x.T.astype(np.float64) @ d
-                sums = x.sum(axis=1)
-        else:
-            self.full_solves += 1
-            x = np.zeros((n, b), dtype=np.int64)
-            free = cap.copy()
-            # Keep previous placements first (up to the new target): per app
-            # the per-slave keepable count has the closed form
-            # min(prev_j, max q: q*d <= free_j + eps), capped cumulatively.
-            for i, a in enumerate(app_ids):
-                if prev_map is None:
-                    pr = prev.x[i] if i < k_prefix else None
-                else:
-                    pr = prev_map.get(a)
-                if pr is None or target[i] <= 0:
-                    continue
-                di = d[i]
-                pos = di > 0
-                if pos.any():
-                    fit = np.floor((free[:, pos] + 1e-9) / di[pos]).min(axis=1)
-                    fit = np.maximum(fit, 0.0).astype(np.int64)
-                else:
-                    fit = np.full(b, int(target[i]), dtype=np.int64)
-                keep = np.minimum(np.asarray(pr, dtype=np.int64), fit)
-                csum = np.minimum(np.cumsum(keep), int(target[i]))
-                keep = np.diff(np.concatenate(([0], csum)))
-                if keep.any():
-                    x[i] = keep
-                    free -= keep[:, None] * di[None, :]
-            sums = x.sum(axis=1)
-            if soa and not integral:
-                # Canonical free (fractional demands, SoA engine): replace
-                # the stickiness loop's sequentially-updated matrix with
-                # one order-independent  cap - x^T d  matmul. Exact no-op
-                # for integral demands (float64 integer products/sums are
-                # associativity-independent); for fractional demands it is
-                # what makes the delta warm start above bit-exact with this
-                # path -- both now derive free from x the same way before
-                # any best-fit score is computed.
-                free = cap - x.T.astype(np.float64) @ d
-        # Best-fit the remainder. Two passes: every app is raised to its
-        # n_min before anyone is topped up to the full target -- packing
-        # early apps to their whole target first would starve the tail below
-        # n_min on a saturated cluster and spuriously report P2 infeasible.
-        if soa:
-            # Only the apps below target are visited (ascending index order,
-            # same as the legacy scan), and row sums are bookkept instead of
-            # re-reduced per app.
-            memo = epoch = None
-            if changed_track is not None and state is not None:
-                memo = self._futile
-                epoch = state.epoch
-                if epoch != self._futile_epoch:
-                    memo.clear()
-                    self._futile_epoch = epoch
-            # Build the full two-pass schedule up front, memo-skips excluded
-            # (decidable before any placement: a memoized app held >= n_min
-            # at the same epoch, so pass 1 never visits it and its target is
-            # unchanged), and execute it with ONE backend call.
-            pass1 = [int(i) for i in np.flatnonzero(sums < nmin_v)]
-            pass2: List[int] = []
-            for i in np.flatnonzero(sums < target):
-                i = int(i)
-                if memo is not None:
-                    # Skip a top-up that already found no fitting slave at
-                    # this capacity epoch (no capacity was freed since, so
-                    # the attempt is provably a no-op; such apps already
-                    # hold >= n_min from the previous allocation).
-                    rec = memo.get(app_ids[i])
-                    if rec is not None and rec[0] == epoch \
-                            and rec[1] == int(target[i]):
-                        continue
-                pass2.append(i)
-            schedule = [(i, int(nmin_v[i])) for i in pass1] \
-                + [(i, int(target[i])) for i in pass2]
-            grants = place_be.place_run(x, free, d, inv_cap, schedule) \
-                if schedule else []
-            # Replay the sequential bookkeeping over the fused results:
-            # per-app row sums, changed-row tracking, the below-n_min
-            # infeasibility abort and the futile-top-up memo updates stop
-            # exactly where the sequential loop would have stopped.
-            for k, i in enumerate(pass1):
-                if grants[k]:
-                    sums[i] += grants[k]
-                    if changed_track is not None and in_prev(i):
-                        changed_track.add(i)
-            for k, i in enumerate(pass2):
-                tgt_i = int(target[i])
-                if sums[i] >= tgt_i:
-                    # Raised to target by pass 1 already: the sequential
-                    # pass-2 scan (computed on post-pass-1 sums) never
-                    # visits this app; its fused grant is provably zero.
-                    continue
-                g = grants[len(pass1) + k]
-                if g:
-                    sums[i] += g
-                    if changed_track is not None and in_prev(i):
-                        changed_track.add(i)
-                if sums[i] < nmin_v[i]:
-                    # Packing failed below n_min -> infeasible signal.
-                    return None
-                if memo is not None:
-                    if sums[i] < tgt_i:
-                        memo[app_ids[i]] = (epoch, tgt_i)
+            elif self.cfg.incremental:
+                if state is not None:
+                    if integral:
+                        # O(m) probe against the incrementally-maintained
+                        # aggregate n_max demand (exact for integral demands)
+                        # instead of the O(n*m) re-aggregation in
+                        # `drf.saturating_counts`.
+                        fast = state.saturates_at_nmax()
                     else:
-                        memo.pop(app_ids[i], None)
-        else:
-            for i in range(n):
-                if sums[i] < apps[i].n_min:
-                    place_fn(x, free, d, inv_cap, i, apps[i].n_min)
-            for i in range(n):
-                if x[i].sum() < target[i]:
-                    place_fn(x, free, d, inv_cap, i, int(target[i]))
-                if x[i].sum() < apps[i].n_min:
-                    # Packing failed below n_min: give up -> infeasible.
-                    return None
-            sums = x.sum(axis=1)
-
-        # -- step 3: adjustment budget.
-        if k_prefix:
-            common = list(range(k_prefix))
-        elif prev_map:
-            common = [i for i, a in enumerate(app_ids) if a in prev_map]
-        else:
-            common = []
-        if common:
-            budget_r = adjust_budget(self.cfg, len(common))
-            if changed_track is not None:
-                # Delta path: rows start as prev's rows, so the placement
-                # grants above are EXACTLY the changed rows -- no compare.
-                changed = sorted(changed_track)
-            elif soa and k_prefix:
-                diff = (x[:k_prefix] != prev.x).any(axis=1)
-                changed = np.flatnonzero(diff).tolist()
+                        # Fractional demands: the running aggregate is not
+                        # ulp-exact, so probe against a fresh aggregation
+                        # (same arithmetic as `drf.saturating_counts`, on the
+                        # state's SoA arrays via the backend seam).
+                        fast = self.backend.saturating_probe(
+                            d, nmax_v.astype(np.float64), total_cap)
+                    if fast:
+                        self.drf.fast_hits += 1
+                        target = nmax_v.astype(np.int64, copy=True)
+                        s_hat_vec = _shares_vec(target, d, total_cap)
+                        self._last_shares = None          # built lazily
+                        self._last_share_ids = app_ids
+                    else:
+                        # Full ladder refill straight on the SoA arrays (the
+                        # backend seam: numpy = the reference fill, jax = the
+                        # jitted ladder program); shares follow in one
+                        # vectorized pass, dict built lazily.
+                        self.drf.full_refills += 1
+                        target = self.backend.ladder_counts(
+                            d, nmin_v, nmax_v,
+                            state.weight[idx].astype(np.float64), total_cap)
+                        s_hat_vec = _shares_vec(target, d, total_cap)
+                        self._last_shares = None          # built lazily
+                        self._last_share_ids = app_ids
+                else:
+                    # Incremental DRF refill: O(n*m) saturating fast path when
+                    # it provably matches the full filling, full otherwise.
+                    drf_counts, shares, fast = self.drf.targets(
+                        apps_fill, cluster, reference=not soa)
+                    self.last_shares = shares
+                    s_hat_vec = np.fromiter((shares[a] for a in app_ids),
+                                            np.float64, n)
+                    target = np.fromiter((drf_counts[a] for a in app_ids),
+                                         np.int64, n)
             else:
-                changed = [i for i in common
-                           if not np.array_equal(x[i], prev_row(i))]
-            # Revert least-valuable changes until within budget (reverting must
-            # stay capacity-feasible; reverts free or consume capacity).
-            changed.sort(key=lambda i: util_w[i] * (sums[i]
-                                                    - prev_row(i).sum()))
-            if len(changed) > budget_r:
-                used = x.T.astype(np.float64) @ d       # (b, m)
-                while len(changed) > budget_r:
-                    reverted = False
-                    for pos_i in range(len(changed) - 1, -1, -1):
-                        i = changed[pos_i]
-                        pr = prev_row(i)
-                        pr_n = int(pr.sum())
-                        if pr_n > nmax_v[i] or pr_n < nmin_v[i]:
-                            # Bounds moved since the previous allocation
-                            # (Resize event): the old row is no longer a
-                            # legal state to revert to.
-                            continue
-                        delta_u = (pr - x[i]).astype(np.float64)[:, None] \
-                            * d[i][None, :]
-                        if np.all(used + delta_u <= cap + 1e-6):
-                            used += delta_u
-                            x[i] = pr
-                            sums[i] = pr_n
-                            changed.pop(pos_i)
-                            reverted = True
-                            break
-                    if not reverted:
-                        return None     # cannot satisfy Eq 16 -> infeasible
-            # Re-check fairness budget after reverts; if blown, also infeasible
-            # (paper keeps previous allocation in that case).
-            if total_loss(sums) > budget_l + 1e-6:
-                drf_loss = total_loss(np.clip(drf_target0, nmin_v, nmax_v))
-                if drf_loss <= budget_l + 1e-6:
-                    return None
-            if soa:
-                self.last_changed = tuple(app_ids[i] for i in changed)
-        elif soa:
-            self.last_changed = ()
+                # Full re-solve semantics (the seed's per-event behaviour):
+                # progressive filling from scratch on every event.
+                drf_counts, s_hat_vec = _drf_targets(apps_fill, cluster,
+                                                     reference=not soa, d=d)
+                self.last_shares = dict(zip(app_ids, map(float, s_hat_vec)))
+                target = np.fromiter((drf_counts[a] for a in app_ids),
+                                     np.int64, n)
+            self.last_shares_vec = s_hat_vec
 
-        if delta:
-            if integral:
-                # Provably feasible, skip the O(n*b) re-validation: rows
-                # start from the (validated) previous allocation, every
-                # grant stayed within the exactly-maintained free capacity
-                # (exact for integral demands), and counts end in
-                # [n_min, target <= n_max]. The legacy engine still
-                # validates, so the engine bit-exactness tests cross-check
-                # this proof.
-                return Allocation.trusted(app_ids, x)
-            # Fractional demands: the free matrix carries rounding, so the
-            # feasibility proof is only epsilon-exact -- keep the cheap
-            # trusted construction but run the full capacity/bounds check.
-            alloc = Allocation.trusted(app_ids, x)
+        with self.spans.span("optimizer.targets"):
+            # -- step 1: choose target counts.
+            if np.any(target < nmin_v):
+                # Aggregate capacity cannot host every app's minimum ->
+                # infeasible; paper behaviour: keep existing allocations
+                # (master handles it).
+                return None
+
+            def total_loss(counts: np.ndarray) -> float:
+                return float(np.abs(g * counts - s_hat_vec).sum())
+
+            drf_target0 = target       # pre-push DRF point (step-3 re-check)
+
+            # The master appends new apps after surviving ones, so prev's app
+            # list is almost always a prefix of the current one; membership is
+            # then just an index compare and NO prev dict is built at all.
+            # Otherwise: row views, not copies (as_dict copies every row; this
+            # runs per event and the solver only reads previous rows).
+            n_prev = len(prev.app_ids) if prev is not None else 0
+            k_prefix = 0
+            prev_map: Optional[Dict[str, np.ndarray]] = None
+            if soa and n_prev and prev.app_ids == app_ids[:n_prev]:
+                k_prefix = n_prev
+            elif prev is not None:
+                prev_map = dict(zip(prev.app_ids, prev.x))
+            else:
+                prev_map = {}
+
+            def in_prev(i: int) -> bool:
+                return i < k_prefix if prev_map is None \
+                    else app_ids[i] in prev_map
+
+            def prev_row(i: int) -> np.ndarray:
+                return prev.x[i] if prev_map is None else prev_map[app_ids[i]]
+
+            delta = bool(self.cfg.incremental and fast and n_prev
+                         and (prev_map is None
+                              or set(prev_map).issubset(app_ids)))
+            if delta:
+                # Guard: a shrunk bound (Resize event) can push a target below
+                # the previous count; the stickiness loop must then TRIM rows,
+                # so the prev-rows warm start would not match -- full path.
+                if state is not None:
+                    if bool((state.counts[idx] > target).any()):
+                        delta = False
+                elif prev_map is None:
+                    if bool((prev.x.sum(axis=1) > target[:k_prefix]).any()):
+                        delta = False
+                else:
+                    tgt_of = dict(zip(app_ids, target.tolist()))
+                    if any(int(row.sum()) > tgt_of[a]
+                           for a, row in prev_map.items()):
+                        delta = False
+            if delta and not integral and not soa:
+                # Legacy-engine guard: with fractional demands (e.g. Philly
+                # n_cpus/n_gpus or Alibaba plan_cpu/100 replays) the delta
+                # path's one-matmul free computation and the legacy full path's
+                # sequential row subtraction can differ in the last ulp and
+                # flip a near-tied best-fit argmin. The SoA engine closes that
+                # hole by CANONICALIZING free on both paths (one
+                # cap - x^T d matmul, order-independent -- see the warm-start
+                # block below), so fractional replays take the delta path
+                # there; the legacy engine stays the frozen reference.
+                delta = False
+
+            if not fast:
+                # Greedy utilization push above the DRF point within the Eq-15
+                # budget (skipped on the fast path: every target already sits
+                # at n_max, so the push is provably a no-op). Pure-python
+                # incremental loop: the loss delta of one extra container is
+                # local to the app, so the Eq-15 re-check is O(1), not O(n).
+                remaining = (total_cap - target @ d).tolist()
+                d_list = d.tolist()
+                g_list = g.tolist()
+                s_hat_list = s_hat_vec.tolist()
+                tgt = target.tolist()
+                nmax_list = nmax_v.tolist()
+                cur_loss = sum(abs(g_list[i] * tgt[i] - s_hat_list[i])
+                               for i in range(n))
+                order = np.argsort(-util_w).tolist()  # best utilization first
+                rng_m = range(m)
+                improved = True
+                while improved:
+                    improved = False
+                    for i in order:
+                        if tgt[i] >= nmax_list[i]:
+                            continue
+                        di = d_list[i]
+                        if any(di[k] > remaining[k] + 1e-9 for k in rng_m):
+                            continue
+                        old_li = abs(g_list[i] * tgt[i] - s_hat_list[i])
+                        new_li = abs(g_list[i] * (tgt[i] + 1) - s_hat_list[i])
+                        if cur_loss - old_li + new_li <= budget_l + 1e-9:
+                            tgt[i] += 1
+                            cur_loss += new_li - old_li
+                            for k in rng_m:
+                                remaining[k] -= di[k]
+                            improved = True
+                target = np.array(tgt, dtype=np.int64)
+
+        with self.spans.span("optimizer.place"):
+            # -- step 2: placement with stickiness. The backend seam covers the
+            # SoA state-backed solves (the master's hot path); spec-only solves
+            # (MILP warm starts, standalone calls) keep the host scatter.
+            if not soa:
+                place_fn = _best_fit_place
+                place_be = None
+            else:
+                # The whole two-pass placement schedule is executed by ONE
+                # backend call (`Backend.place_run`): numpy runs the reference
+                # sequential loop, jax fuses the schedule into a single device
+                # program. Spec-only SoA solves stay on the host backend.
+                place_fn = None
+                place_be = self.backend if state is not None else _HOST_BACKEND
+            inv_cap = 1.0 / np.maximum(cap, 1e-9)
+            # Indices changed vs prev rows.
+            changed_track: Optional[set] = None
+            if delta:
+                # Delta warm start: every surviving app keeps its previous row
+                # verbatim (the stickiness loop below would reproduce exactly
+                # that: targets are at n_max >= previous counts, and previous
+                # rows are jointly capacity-feasible, so nothing is trimmed).
+                self.delta_solves += 1
+                # Only the SoA placement loops feed the tracker; the legacy
+                # engine must fall back to the row compare.
+                changed_track = set() if soa else None
+                if state is not None:
+                    # The state's rows ARE the previous allocation: one gather
+                    # for x, one copy of the incrementally-maintained free
+                    # matrix -- no per-app row loop, no (b, n) @ (n, m) matmul.
+                    x = state.x[idx]                # fancy index -> fresh copy
+                    if integral:
+                        free = state.free.copy()
+                    else:
+                        # Fractional demands: derive free canonically from x
+                        # (one order-independent matmul). The full path below
+                        # canonicalizes its free the same way after the
+                        # stickiness loop, so both paths feed the best-fit
+                        # scatter bit-identical scores -- for integral demands
+                        # the incrementally-maintained matrix already IS that
+                        # value exactly, and the copy is cheaper.
+                        free = cap - x.T.astype(np.float64) @ d
+                    sums = state.counts[idx].copy()
+                else:
+                    x = np.zeros((n, b), dtype=np.int64)
+                    if k_prefix:
+                        x[:k_prefix] = prev.x       # one bulk copy
+                    else:
+                        for i, a in enumerate(app_ids):
+                            pr = prev_map.get(a)
+                            if pr is not None:
+                                x[i] = pr
+                    free = cap - x.T.astype(np.float64) @ d
+                    sums = x.sum(axis=1)
+            else:
+                self.full_solves += 1
+                x = np.zeros((n, b), dtype=np.int64)
+                free = cap.copy()
+                # Keep previous placements first (up to the new target): per
+                # app the per-slave keepable count has the closed form
+                # min(prev_j, max q: q*d <= free_j + eps), capped cumulatively.
+                for i, a in enumerate(app_ids):
+                    if prev_map is None:
+                        pr = prev.x[i] if i < k_prefix else None
+                    else:
+                        pr = prev_map.get(a)
+                    if pr is None or target[i] <= 0:
+                        continue
+                    di = d[i]
+                    pos = di > 0
+                    if pos.any():
+                        fit = np.floor((free[:, pos] + 1e-9)
+                                       / di[pos]).min(axis=1)
+                        fit = np.maximum(fit, 0.0).astype(np.int64)
+                    else:
+                        fit = np.full(b, int(target[i]), dtype=np.int64)
+                    keep = np.minimum(np.asarray(pr, dtype=np.int64), fit)
+                    csum = np.minimum(np.cumsum(keep), int(target[i]))
+                    keep = np.diff(np.concatenate(([0], csum)))
+                    if keep.any():
+                        x[i] = keep
+                        free -= keep[:, None] * di[None, :]
+                sums = x.sum(axis=1)
+                if soa and not integral:
+                    # Canonical free (fractional demands, SoA engine): replace
+                    # the stickiness loop's sequentially-updated matrix with
+                    # one order-independent  cap - x^T d  matmul. Exact no-op
+                    # for integral demands (float64 integer products/sums are
+                    # associativity-independent); for fractional demands it is
+                    # what makes the delta warm start above bit-exact with this
+                    # path -- both now derive free from x the same way before
+                    # any best-fit score is computed.
+                    free = cap - x.T.astype(np.float64) @ d
+            # Best-fit the remainder. Two passes: every app is raised to its
+            # n_min before anyone is topped up to the full target -- packing
+            # early apps to their whole target first would starve the tail
+            # below n_min on a saturated cluster and spuriously report P2
+            # infeasible.
+            if soa:
+                # Only the apps below target are visited (ascending index
+                # order, same as the legacy scan), and row sums are bookkept
+                # instead of re-reduced per app.
+                memo = epoch = None
+                if changed_track is not None and state is not None:
+                    memo = self._futile
+                    epoch = state.epoch
+                    if epoch != self._futile_epoch:
+                        memo.clear()
+                        self._futile_epoch = epoch
+                # Build the full two-pass schedule up front, memo-skips
+                # excluded (decidable before any placement: a memoized app held
+                # >= n_min at the same epoch, so pass 1 never visits it and its
+                # target is unchanged), and execute it with ONE backend call.
+                pass1 = [int(i) for i in np.flatnonzero(sums < nmin_v)]
+                pass2: List[int] = []
+                for i in np.flatnonzero(sums < target):
+                    i = int(i)
+                    if memo is not None:
+                        # Skip a top-up that already found no fitting slave at
+                        # this capacity epoch (no capacity was freed since, so
+                        # the attempt is provably a no-op; such apps already
+                        # hold >= n_min from the previous allocation).
+                        rec = memo.get(app_ids[i])
+                        if rec is not None and rec[0] == epoch \
+                                and rec[1] == int(target[i]):
+                            continue
+                    pass2.append(i)
+                schedule = [(i, int(nmin_v[i])) for i in pass1] \
+                    + [(i, int(target[i])) for i in pass2]
+                grants = place_be.place_run(x, free, d, inv_cap, schedule) \
+                    if schedule else []
+                # Replay the sequential bookkeeping over the fused results:
+                # per-app row sums, changed-row tracking, the below-n_min
+                # infeasibility abort and the futile-top-up memo updates stop
+                # exactly where the sequential loop would have stopped.
+                for k, i in enumerate(pass1):
+                    if grants[k]:
+                        sums[i] += grants[k]
+                        if changed_track is not None and in_prev(i):
+                            changed_track.add(i)
+                for k, i in enumerate(pass2):
+                    tgt_i = int(target[i])
+                    if sums[i] >= tgt_i:
+                        # Raised to target by pass 1 already: the sequential
+                        # pass-2 scan (computed on post-pass-1 sums) never
+                        # visits this app; its fused grant is provably zero.
+                        continue
+                    g = grants[len(pass1) + k]
+                    if g:
+                        sums[i] += g
+                        if changed_track is not None and in_prev(i):
+                            changed_track.add(i)
+                    if sums[i] < nmin_v[i]:
+                        # Packing failed below n_min -> infeasible signal.
+                        return None
+                    if memo is not None:
+                        if sums[i] < tgt_i:
+                            memo[app_ids[i]] = (epoch, tgt_i)
+                        else:
+                            memo.pop(app_ids[i], None)
+            else:
+                for i in range(n):
+                    if sums[i] < apps[i].n_min:
+                        place_fn(x, free, d, inv_cap, i, apps[i].n_min)
+                for i in range(n):
+                    if x[i].sum() < target[i]:
+                        place_fn(x, free, d, inv_cap, i, int(target[i]))
+                    if x[i].sum() < apps[i].n_min:
+                        # Packing failed below n_min: give up -> infeasible.
+                        return None
+                sums = x.sum(axis=1)
+
+        with self.spans.span("optimizer.budget"):
+            # -- step 3: adjustment budget.
+            if k_prefix:
+                common = list(range(k_prefix))
+            elif prev_map:
+                common = [i for i, a in enumerate(app_ids) if a in prev_map]
+            else:
+                common = []
+            if common:
+                budget_r = adjust_budget(self.cfg, len(common))
+                if changed_track is not None:
+                    # Delta path: rows start as prev's rows, so the placement
+                    # grants above are EXACTLY the changed rows -- no compare.
+                    changed = sorted(changed_track)
+                elif soa and k_prefix:
+                    diff = (x[:k_prefix] != prev.x).any(axis=1)
+                    changed = np.flatnonzero(diff).tolist()
+                else:
+                    changed = [i for i in common
+                               if not np.array_equal(x[i], prev_row(i))]
+                # Revert least-valuable changes until within budget (reverting
+                # must stay capacity-feasible; reverts free or consume
+                # capacity).
+                changed.sort(key=lambda i: util_w[i] * (sums[i]
+                                                        - prev_row(i).sum()))
+                if len(changed) > budget_r:
+                    used = x.T.astype(np.float64) @ d       # (b, m)
+                    while len(changed) > budget_r:
+                        reverted = False
+                        for pos_i in range(len(changed) - 1, -1, -1):
+                            i = changed[pos_i]
+                            pr = prev_row(i)
+                            pr_n = int(pr.sum())
+                            if pr_n > nmax_v[i] or pr_n < nmin_v[i]:
+                                # Bounds moved since the previous allocation
+                                # (Resize event): the old row is no longer a
+                                # legal state to revert to.
+                                continue
+                            delta_u = (pr - x[i]).astype(np.float64)[:, None] \
+                                * d[i][None, :]
+                            if np.all(used + delta_u <= cap + 1e-6):
+                                used += delta_u
+                                x[i] = pr
+                                sums[i] = pr_n
+                                changed.pop(pos_i)
+                                reverted = True
+                                break
+                        if not reverted:
+                            # Cannot satisfy Eq 16 -> infeasible.
+                            return None
+                # Re-check fairness budget after reverts; if blown, also
+                # infeasible (paper keeps previous allocation in that case).
+                if total_loss(sums) > budget_l + 1e-6:
+                    drf_loss = total_loss(np.clip(drf_target0, nmin_v, nmax_v))
+                    if drf_loss <= budget_l + 1e-6:
+                        return None
+                if soa:
+                    self.last_changed = tuple(app_ids[i] for i in changed)
+            elif soa:
+                self.last_changed = ()
+
+            if delta:
+                if integral:
+                    # Provably feasible, skip the O(n*b) re-validation: rows
+                    # start from the (validated) previous allocation, every
+                    # grant stayed within the exactly-maintained free capacity
+                    # (exact for integral demands), and counts end in
+                    # [n_min, target <= n_max]. The legacy engine still
+                    # validates, so the engine bit-exactness tests cross-check
+                    # this proof.
+                    return Allocation.trusted(app_ids, x)
+                # Fractional demands: the free matrix carries rounding, so the
+                # feasibility proof is only epsilon-exact -- keep the cheap
+                # trusted construction but run the full capacity/bounds check.
+                alloc = Allocation.trusted(app_ids, x)
+                validate_allocation(alloc, apps, cluster, d=d)
+                return alloc
+            alloc = Allocation(app_ids, x)
             validate_allocation(alloc, apps, cluster, d=d)
             return alloc
-        alloc = Allocation(app_ids, x)
-        validate_allocation(alloc, apps, cluster, d=d)
-        return alloc
 
 
 class AutoOptimizer:
@@ -1969,10 +2001,12 @@ class AutoOptimizer:
     scale path for 1000-slave clusters where the MILP's n*b integer grid
     is intractable."""
 
-    def __init__(self, cfg: OptimizerConfig = OptimizerConfig()):
+    def __init__(self, cfg: OptimizerConfig = OptimizerConfig(),
+                 spans: Optional[Spans] = None):
         self.cfg = cfg
-        self._milp = MilpOptimizer(cfg) if _HAVE_SCIPY else None
-        self._greedy = GreedyOptimizer(cfg)
+        self.spans = spans if spans is not None else Spans()
+        self._milp = MilpOptimizer(cfg, self.spans) if _HAVE_SCIPY else None
+        self._greedy = GreedyOptimizer(cfg, self.spans)
         self._last_solver = self._greedy
 
     @property
@@ -1987,14 +2021,9 @@ class AutoOptimizer:
     def last_changed(self) -> Optional[Tuple[str, ...]]:
         return self._last_solver.last_changed
 
-    @property
-    def refill_s(self) -> float:
-        return self._greedy.refill_s + \
-            (self._milp.refill_s if self._milp is not None else 0.0)
-
-    @property
-    def pricing_s(self) -> float:
-        return self._milp.pricing_s if self._milp is not None else 0.0
+    # Both solvers record into `self.spans`.
+    refill_s = MilpOptimizer.refill_s
+    pricing_s = MilpOptimizer.pricing_s
 
     @property
     def backend(self):
@@ -2026,16 +2055,20 @@ class AutoOptimizer:
         return alloc
 
 
-def make_optimizer(kind: str, cfg: OptimizerConfig = OptimizerConfig()):
+def make_optimizer(kind: str, cfg: OptimizerConfig = OptimizerConfig(),
+                   spans: Optional[Spans] = None):
+    """`spans`: the owner's span registry (a DormMaster hands over its own);
+    a fresh one when None."""
     if kind == "milp":
-        return MilpOptimizer(cfg)
+        return MilpOptimizer(cfg, spans)
     if kind == "colgen":
         # The column-generation exact route: a MilpOptimizer with the
         # colgen path forced on (certified global gap on every solve).
         return MilpOptimizer(dataclasses.replace(cfg,
-                                                 column_generation=True))
+                                                 column_generation=True),
+                             spans)
     if kind == "greedy":
-        return GreedyOptimizer(cfg)
+        return GreedyOptimizer(cfg, spans)
     if kind == "auto":
-        return AutoOptimizer(cfg)
+        return AutoOptimizer(cfg, spans)
     raise ValueError(f"unknown optimizer kind: {kind!r}")

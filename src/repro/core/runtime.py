@@ -36,6 +36,7 @@ from typing import (Any, Callable, Dict, List, Optional, Protocol, Sequence,
 
 import numpy as np
 
+from .telemetry import Spans
 from .types import Allocation, ApplicationSpec
 from .workload import WorkloadApp
 
@@ -349,29 +350,28 @@ def as_policy(scheduler: Any) -> Any:
 class PolicyTimer:
     """Transparent SchedulerPolicy wrapper that measures per-event scheduling
     wall time -- the quantity the paper calls per-event sharing overhead and
-    benchmarks/bench_scale.py reports as `per_event_policy_ms`."""
+    benchmarks/bench_scale.py reports as `per_event_policy_ms`.
+
+    Every event is charged the full wall time of the pass that decided it
+    (an absorbed flood of K events books K entries of the whole pass), jit
+    compiles included. `compile_s` adds up the policy's
+    `backend_compile_s` growth over the timed calls."""
 
     def __init__(self, policy: Any):
         self.policy = as_policy(policy)
         self.calls: List[Tuple[str, float]] = []     # (kind, seconds)
-        # jit-compile seconds excluded from `calls` (jax backend only):
-        # first-event compilation is a process-lifetime one-off, so booking
-        # it into that event's time would poison per-event medians/means.
-        # Reported separately (bench_scale's backend_compile_s).
         self.compile_s = 0.0
 
-    def _timed(self, kind: str, fn, *args):
+    def _timed(self, kind: str, fn, *args, k: int = 1, **kw):
         c0 = getattr(self.policy, "backend_compile_s", 0.0)
         t0 = _time.perf_counter()
         try:
-            return fn(*args)
+            return fn(*args, **kw)
         finally:
             dt = _time.perf_counter() - t0
-            dc = getattr(self.policy, "backend_compile_s", 0.0) - c0
-            if dc > 0.0:
-                self.compile_s += dc
-                dt = max(dt - dc, 0.0)
-            self.calls.append((kind, dt))
+            self.compile_s += getattr(self.policy, "backend_compile_s",
+                                      0.0) - c0
+            self.calls.extend([(kind, dt)] * k)
 
     def on_arrival(self, specs):
         return self._timed("arrival", self.policy.on_arrival, specs)
@@ -387,26 +387,15 @@ class PolicyTimer:
         return self._timed("tick", self.policy.on_tick, t)
 
     def _on_batch_timed(self, completions, resizes, arrivals, chaos=()):
-        """One absorbed flood of K events: book K per-event-AMORTIZED
-        entries under the `absorb` kind so medians/means stay comparable
-        with per-event runs (a 10-event pass at 5 ms is 10 entries of
-        0.5 ms, not one 5 ms outlier)."""
+        """One absorbed flood of K events: K entries under the `absorb`
+        kind, each the whole pass's wall time."""
         k = max(len(completions) + len(resizes) + len(arrivals)
                 + len(chaos), 1)
-        c0 = getattr(self.policy, "backend_compile_s", 0.0)
-        t0 = _time.perf_counter()
-        try:
-            if chaos:
-                return self.policy.on_batch(completions, resizes, arrivals,
-                                            chaos=chaos)
-            return self.policy.on_batch(completions, resizes, arrivals)
-        finally:
-            dt = _time.perf_counter() - t0
-            dc = getattr(self.policy, "backend_compile_s", 0.0) - c0
-            if dc > 0.0:
-                self.compile_s += dc
-                dt = max(dt - dc, 0.0)
-            self.calls.extend([("absorb", dt / k)] * k)
+        if chaos:
+            return self._timed("absorb", self.policy.on_batch, completions,
+                               resizes, arrivals, k=k, chaos=chaos)
+        return self._timed("absorb", self.policy.on_batch, completions,
+                           resizes, arrivals, k=k)
 
     def containers_of(self, app_id):
         return self.policy.containers_of(app_id)
@@ -662,6 +651,15 @@ class ClusterRuntime:
             "events": 0, "passes": 0, "batches": 0,
             "absorbed_events": 0, "batch_hist": {}}
         self._lat_ewma: Optional[float] = None
+        # The event loop's spans: `runtime.scan` (a step's O(trace length)
+        # passes over the slot arrays: the next completion and the
+        # progress up to it), `runtime.collect` (the absorber's flood
+        # collection, whose own scans it holds), `runtime.pass` (one policy
+        # call; metadata `pass_id`, `k` events; the policy's own spans nest
+        # inside it) and `runtime.finish` (applying, sampling and
+        # publishing a decision).
+        self.spans = Spans()
+        self._pass_id = 0
 
     def _window_s(self) -> float:
         """Current absorber window: fixed, or latency-adaptive (EWMA of
@@ -719,6 +717,7 @@ class ClusterRuntime:
         rate_mult = self.rate_multiplier
         use_batch = self.batch_window_s > 0
         absorb = self.absorber is not None
+        spans = self.spans
 
         def rates() -> np.ndarray:
             """Per-slot progress rate. Batch jobs burn container-seconds
@@ -753,6 +752,12 @@ class ClusterRuntime:
             if not np.isfinite(tf[s]):
                 return np.inf, None
             return float(tf[s]), s
+
+        def decide(k: int, fn, *args, **kw) -> Optional[ReallocationResult]:
+            """One policy pass deciding `k` events."""
+            self._pass_id += 1
+            with spans.span("runtime.pass", pass_id=self._pass_id, k=k):
+                return fn(*args, **kw)
 
         def apply(res: ReallocationResult) -> None:
             if res.changed_counts is not None:
@@ -806,12 +811,18 @@ class ClusterRuntime:
             active[s] = True
             return s
 
-        def finish(event: Event, res: Optional[ReallocationResult]) -> None:
-            self.bus.publish(event)
-            if res is not None:
-                apply(res)
-                self._sample(res, t)
-                self.bus.publish(Reallocated(t, event, res))
+        def finish(event: Event, res: Optional[ReallocationResult],
+                   before: Sequence[Event] = ()) -> None:
+            """Publish `before` and `event`, then apply, sample and publish
+            the decision `res` (None: nothing was decided)."""
+            with spans.span("runtime.finish"):
+                for ev in before:
+                    self.bus.publish(ev)
+                self.bus.publish(event)
+                if res is not None:
+                    apply(res)
+                    self._sample(res, t)
+                    self.bus.publish(Reallocated(t, event, res))
 
         while True:
             t_arr = (arrivals[ai].spec.submit_time
@@ -820,12 +831,13 @@ class ClusterRuntime:
             # time never moves backwards.
             t_inj = max(inj_heap[0][0], t) if inj_heap else np.inf
             t_ext = min(t_inj, next_tick)
-            t_fin, fin_slot = next_completion()
-            t_next = min(t_arr, t_fin, t_ext)
-            if not np.isfinite(t_next) or t_next > self.horizon_s:
-                advance(t, min(self.horizon_s, t_next))
+            with spans.span("runtime.scan"):
+                t_fin, fin_slot = next_completion()
+                t_next = min(t_arr, t_fin, t_ext)
+                done = not np.isfinite(t_next) or t_next > self.horizon_s
+                advance(t, min(self.horizon_s, t_next) if done else t_next)
+            if done:
                 break
-            advance(t, t_next)
             t = t_next
 
             if absorb:
@@ -857,56 +869,57 @@ class ClusterRuntime:
                     batch_a: List[WorkloadApp] = []
                     batch_x: List[ChaosEvent] = []
                     pubs: List[Event] = []
-                    while True:
-                        t_arr = (arrivals[ai].spec.submit_time
-                                 if ai < n_total else np.inf)
-                        t_inj = max(inj_heap[0][0], t) if inj_heap else np.inf
-                        t_ext = min(t_inj, next_tick)
-                        t_fin, fin_slot = next_completion()
-                        if min(t_arr, t_fin, t_ext) > t_end:
-                            break
-                        if (t_fin <= t_arr and t_fin <= t_ext
-                                and fin_slot is not None):
-                            advance(t, t_fin)
-                            t = t_fin
-                            app_id = slot_ids[fin_slot]
-                            rt = self.runtimes[app_id]
-                            rt.finished_at = t
-                            rt.remaining_work = float(rem[fin_slot])
-                            rt.containers = 0
-                            rt.paused_until = float(paused[fin_slot])
-                            active[fin_slot] = False
-                            cont[fin_slot] = 0
-                            del slot_of[app_id]
-                            curved.pop(fin_slot, None)
-                            batch_c.append(app_id)
-                            pubs.append(Completion(t, app_id))
-                        elif t_ext <= t_arr:
-                            if not (t_inj <= next_tick and isinstance(
-                                    inj_heap[0][2], inj_abs)):
-                                break         # tick / foreign injection
-                            ev = heapq.heappop(inj_heap)[2]
-                            advance(t, t_inj)
-                            t = t_inj
-                            if isinstance(ev, _CHAOS_TYPES):
-                                batch_x.append(ev)
-                                pubs.append(ev)
-                                continue
-                            s = slot_of.get(ev.app_id)
-                            if s is not None and active[s]:
-                                batch_r.append(ev)
-                                pubs.append(ev)
+                    with spans.span("runtime.collect"):
+                        while True:
+                            t_arr = (arrivals[ai].spec.submit_time
+                                     if ai < n_total else np.inf)
+                            t_inj = max(inj_heap[0][0], t) if inj_heap else np.inf
+                            t_ext = min(t_inj, next_tick)
+                            t_fin, fin_slot = next_completion()
+                            if min(t_arr, t_fin, t_ext) > t_end:
+                                break
+                            if (t_fin <= t_arr and t_fin <= t_ext
+                                    and fin_slot is not None):
+                                advance(t, t_fin)
+                                t = t_fin
+                                app_id = slot_ids[fin_slot]
+                                rt = self.runtimes[app_id]
+                                rt.finished_at = t
+                                rt.remaining_work = float(rem[fin_slot])
+                                rt.containers = 0
+                                rt.paused_until = float(paused[fin_slot])
+                                active[fin_slot] = False
+                                cont[fin_slot] = 0
+                                del slot_of[app_id]
+                                curved.pop(fin_slot, None)
+                                batch_c.append(app_id)
+                                pubs.append(Completion(t, app_id))
+                            elif t_ext <= t_arr:
+                                if not (t_inj <= next_tick and isinstance(
+                                        inj_heap[0][2], inj_abs)):
+                                    break         # tick / foreign injection
+                                ev = heapq.heappop(inj_heap)[2]
+                                advance(t, t_inj)
+                                t = t_inj
+                                if isinstance(ev, _CHAOS_TYPES):
+                                    batch_x.append(ev)
+                                    pubs.append(ev)
+                                    continue
+                                s = slot_of.get(ev.app_id)
+                                if s is not None and active[s]:
+                                    batch_r.append(ev)
+                                    pubs.append(ev)
+                                else:
+                                    # Dead-target resize: published with no
+                                    # result, exactly like the per-event path.
+                                    finish(ev, None)
                             else:
-                                # Dead-target resize: published with no
-                                # result, exactly like the per-event path.
-                                finish(ev, None)
-                        else:
-                            w = arrivals[ai]
-                            ai += 1
-                            advance(t, t_arr)
-                            t = t_arr
-                            admit(w, t_arr)
-                            batch_a.append(w)
+                                w = arrivals[ai]
+                                ai += 1
+                                advance(t, t_arr)
+                                t = t_arr
+                                admit(w, t_arr)
+                                batch_a.append(w)
                     k = (len(batch_c) + len(batch_r) + len(batch_a)
                          + len(batch_x))
                     st = self.absorber_stats
@@ -922,39 +935,35 @@ class ClusterRuntime:
                         # per-event hooks so unabsorbed timelines stay
                         # bit-identical to an absorber-free run.
                         if batch_c:
-                            finish(pubs[0],
-                                   self.policy.on_completion(batch_c[0]))
+                            finish(pubs[0], decide(
+                                1, self.policy.on_completion, batch_c[0]))
                         elif batch_r:
                             ev = batch_r[0]
-                            finish(ev, self.policy.on_resize(
-                                ev.app_id, ev.n_min, ev.n_max))
+                            finish(ev, decide(1, self.policy.on_resize,
+                                              ev.app_id, ev.n_min, ev.n_max))
                         elif batch_x:
-                            finish(pubs[0],
-                                   self._dispatch_chaos(batch_x[0]))
+                            finish(pubs[0], decide(1, self._dispatch_chaos,
+                                                   batch_x[0]))
                         else:
                             w = batch_a[0]
-                            finish(Arrival(t, (w.spec,)),
-                                   self.policy.on_arrival((w.spec,)))
+                            finish(Arrival(t, (w.spec,)), decide(
+                                1, self.policy.on_arrival, (w.spec,)))
                     elif k >= 2:
                         specs = tuple(w.spec for w in batch_a)
+                        resizes = tuple((r.app_id, r.n_min, r.n_max)
+                                        for r in batch_r)
                         if batch_x:
-                            res = self.policy.on_batch(
-                                tuple(batch_c),
-                                tuple((r.app_id, r.n_min, r.n_max)
-                                      for r in batch_r),
-                                specs, chaos=tuple(batch_x))
+                            res = decide(k, self.policy.on_batch,
+                                         tuple(batch_c), resizes, specs,
+                                         chaos=tuple(batch_x))
                         else:
-                            res = self.policy.on_batch(
-                                tuple(batch_c),
-                                tuple((r.app_id, r.n_min, r.n_max)
-                                      for r in batch_r),
-                                specs)
-                        for ev in pubs:
-                            self.bus.publish(ev)
+                            res = decide(k, self.policy.on_batch,
+                                         tuple(batch_c), resizes, specs)
                         if specs:
-                            self.bus.publish(Arrival(t, specs))
+                            pubs.append(Arrival(t, specs))
                         finish(Storm(t, tuple(batch_c), tuple(batch_r),
-                                     specs, tuple(batch_x)), res)
+                                     specs, tuple(batch_x)), res,
+                               before=pubs)
                     # k == 0: flood was only dead-target resizes, already
                     # published during collection; nothing to solve.
                     if self.absorber.adaptive and k:
@@ -976,7 +985,7 @@ class ClusterRuntime:
                 del slot_of[app_id]
                 curved.pop(fin_slot, None)
                 finish(Completion(t, app_id),
-                       self.policy.on_completion(app_id))
+                       decide(1, self.policy.on_completion, app_id))
             elif t_ext <= t_arr:
                 if t_inj <= next_tick:
                     ev = heapq.heappop(inj_heap)[2]
@@ -984,23 +993,23 @@ class ClusterRuntime:
                     if isinstance(ev, Resize):
                         s = slot_of.get(ev.app_id)
                         if s is not None and active[s]:
-                            res = self.policy.on_resize(
-                                ev.app_id, ev.n_min, ev.n_max)
+                            res = decide(1, self.policy.on_resize,
+                                         ev.app_id, ev.n_min, ev.n_max)
                     elif isinstance(ev, Tick):
-                        res = self.policy.on_tick(t)
+                        res = decide(1, self.policy.on_tick, t)
                     elif isinstance(ev, Migrate):
                         # First-class migration: route to the sharded
                         # plane's hook. Single-master policies have no
                         # shards to move between -- publish-only.
                         fn = getattr(self.policy, "on_migrate", None)
                         if fn is not None:
-                            res = fn(ev.app_id, ev.dst_shard)
+                            res = decide(1, fn, ev.app_id, ev.dst_shard)
                     elif isinstance(ev, _CHAOS_TYPES):
-                        res = self._dispatch_chaos(ev)
+                        res = decide(1, self._dispatch_chaos, ev)
                     finish(ev, res)
                 else:
                     next_tick += tick_dt
-                    finish(Tick(t), self.policy.on_tick(t))
+                    finish(Tick(t), decide(1, self.policy.on_tick, t))
             elif use_batch:
                 # Event batching: pull in every arrival landing within the
                 # window (and strictly before the next completion or external
@@ -1016,18 +1025,20 @@ class ClusterRuntime:
                     batch.append(arrivals[ai])
                     ai += 1
                 t_last = batch[-1].spec.submit_time
-                advance(t, t_last)
+                with spans.span("runtime.scan"):
+                    advance(t, t_last)
                 t = t_last
                 for w in batch:
                     admit(w, w.spec.submit_time)
                 specs = tuple(w.spec for w in batch)
-                finish(Arrival(t, specs), self.policy.on_arrival(specs))
+                finish(Arrival(t, specs),
+                       decide(len(specs), self.policy.on_arrival, specs))
             else:
                 w = arrivals[ai]
                 ai += 1
                 admit(w, t)
                 finish(Arrival(t, (w.spec,)),
-                       self.policy.on_arrival((w.spec,)))
+                       decide(1, self.policy.on_arrival, (w.spec,)))
 
         # Sync runtime objects from the slot arrays for result consumers.
         for app_id, s in slot_of.items():

@@ -619,7 +619,10 @@ class ShardedControlPlane:
 
     @property
     def backend_compile_s(self) -> float:
-        return float(sum(sh.master.backend_compile_s for sh in self.shards))
+        # The compile count is process-wide: every jax shard reads the same
+        # number, so it is taken once, not summed.
+        return max((sh.master.backend_compile_s for sh in self.shards),
+                   default=0.0)
 
     def phase_breakdown(self) -> Dict[str, float]:
         """Cumulative per-phase seconds summed over shards (same buckets
@@ -628,6 +631,8 @@ class ShardedControlPlane:
         for sh in self.shards:
             for phase, secs in sh.master.phase_breakdown().items():
                 out[phase] = out.get(phase, 0.0) + secs
+        if out:
+            out["backend_compile"] = self.backend_compile_s
         return out
 
     def shard_summaries(self) -> List[Dict[str, Any]]:

@@ -52,6 +52,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 _PARTS = 3
+KERNEL_NAME = "dorm_best_fit"
 # Block indices stay int32 under jax_enable_x64 (Mosaic rejects i64 ones).
 _I0 = np.int32(0)
 
@@ -133,6 +134,8 @@ def best_fit_counts(score: jnp.ndarray, q: jnp.ndarray, need: jnp.ndarray,
         out_specs=pl.BlockSpec((1, bb), lambda j, k: (_I0, j)),
         out_shape=jax.ShapeDtypeStruct((1, b), jnp.int32),
         interpret=interpret,
+        # A stable name for the kernel in compiled text and device traces.
+        name=KERNEL_NAME,
     )(key, key, q2, q2, need2)
     return out.reshape(b)
 

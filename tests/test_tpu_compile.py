@@ -1,7 +1,8 @@
 """The scheduler's device programs compile for a TPU v5e, checked without a
 chip: the chip's compiler builds each program for a described (not
 attached) v5e topology. The Pallas placement kernel must come out as a
-`tpu_custom_call`, alone and inside the fused `place_run` scan.
+`tpu_custom_call`, alone and inside the fused `place_run` scan, under its
+stable name.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -12,7 +13,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.backend import _build_jax_fns
-from repro.kernels.placement import best_fit_counts
+from repro.kernels.placement import KERNEL_NAME, best_fit_counts
 
 SLAVES, RESOURCES, STEPS = 8192, 3, 64
 
@@ -53,6 +54,8 @@ def test_place_run_compiles_with_pallas_kernel(one_chip):
                     ((k, m), jnp.float64), ((k,), jnp.int64),
                     ((k,), jnp.int64), ((k,), jnp.int64))
     assert "tpu_custom_call" in text
+    # The kernel keeps its stable name inside the fused program.
+    assert KERNEL_NAME in text
 
 
 def test_probe_compiles(one_chip):
