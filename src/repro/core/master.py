@@ -298,11 +298,9 @@ class DormMaster:
         self._teardown(app_id)
         if self.prev_alloc is not None \
                 and app_id in self.prev_alloc.app_ids:
-            keep = [i for i, a in enumerate(self.prev_alloc.app_ids)
-                    if a != app_id]
-            self.prev_alloc = Allocation.trusted(
-                tuple(self.prev_alloc.app_ids[i] for i in keep),
-                self.prev_alloc.x[keep])
+            self.prev_alloc = self.prev_alloc.take(
+                [i for i, a in enumerate(self.prev_alloc.app_ids)
+                 if a != app_id])
         if app_id not in self.pending:
             self.pending.append(app_id)
 
@@ -429,11 +427,9 @@ class DormMaster:
             drop = comp_set - cancelled
             if drop and self.prev_alloc is not None \
                     and drop & set(self.prev_alloc.app_ids):
-                keep = [i for i, a in enumerate(self.prev_alloc.app_ids)
-                        if a not in drop]
-                self.prev_alloc = Allocation.trusted(
-                    tuple(self.prev_alloc.app_ids[i] for i in keep),
-                    self.prev_alloc.x[keep])
+                self.prev_alloc = self.prev_alloc.take(
+                    [i for i, a in enumerate(self.prev_alloc.app_ids)
+                     if a not in drop])
             # -- resizes: last-wins per app, dead targets dropped.
             merged: Dict[str, Tuple[Optional[int], Optional[int]]] = {}
             for app_id, n_min, n_max in resizes:
@@ -540,11 +536,9 @@ class DormMaster:
             self.pending.remove(app_id)
         # Drop the finished app from prev_alloc so Eq-4 excludes it.
         if self.prev_alloc is not None and app_id in self.prev_alloc.app_ids:
-            keep = [i for i, a in enumerate(self.prev_alloc.app_ids)
-                    if a != app_id]
-            self.prev_alloc = Allocation.trusted(
-                tuple(self.prev_alloc.app_ids[i] for i in keep),
-                self.prev_alloc.x[keep])
+            self.prev_alloc = self.prev_alloc.take(
+                [i for i, a in enumerate(self.prev_alloc.app_ids)
+                 if a != app_id])
         return self.reallocate()
 
     def running_apps(self) -> List[ApplicationSpec]:
@@ -738,8 +732,7 @@ class DormMaster:
             # Allocation order, matching the legacy engine's adjusted order.
             for app_id in sorted(changed_ids, key=pos.get):
                 if state.is_placed(app_id):
-                    i = pos[app_id]
-                    to_place.append((app_id, alloc.x[i], True))
+                    to_place.append((app_id, alloc.row_at(pos[app_id]), True))
         # Starts: pending apps that received containers.
         if self.pending:
             if pos is None:
@@ -747,12 +740,12 @@ class DormMaster:
             hits = []
             for app_id in self.pending:
                 i = pos.get(app_id)
-                if i is not None and alloc.x[i].any():
+                if i is not None and alloc.row_at(i).any():
                     hits.append(i)
             # Allocation order, matching the legacy engine's started order
             # (chaos parking appends to pending out of specs order).
             for i in sorted(hits):
-                to_place.append((alloc.app_ids[i], alloc.x[i], False))
+                to_place.append((alloc.app_ids[i], alloc.row_at(i), False))
         return to_place
 
     # ------------------------------------------------------------- internal
@@ -804,10 +797,7 @@ class DormMaster:
                 keep = [i for i, a in enumerate(alloc.app_ids)
                         if a in self.specs]
                 apps = [self.specs[alloc.app_ids[i]] for i in keep]
-                sub = Allocation.trusted(tuple(alloc.app_ids[i] for i in keep),
-                                         alloc.x[keep] if keep
-                                         else np.zeros((0, self.cluster.b),
-                                                       np.int64))
+                sub = alloc.take(keep)
             d = totals = None
             if self.state is not None and apps:
                 idx = self.state.rows_for([a.app_id for a in apps])

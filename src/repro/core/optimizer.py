@@ -45,6 +45,7 @@ and the DormMaster keeps the previous allocation (new apps stay pending).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -1436,6 +1437,15 @@ def _best_fit_place_batch(x: np.ndarray, free: np.ndarray, d: np.ndarray,
     return True
 
 
+@functools.lru_cache(maxsize=8)
+def _zero_row(b: int) -> np.ndarray:
+    """The one read-only zeros row a delta solve gives every new app that
+    placed nothing (row-form allocations share it)."""
+    row = np.zeros(b, np.int64)
+    row.flags.writeable = False
+    return row
+
+
 class GreedyOptimizer:
     """DRF-guided heuristic for P2 with placement stickiness.
 
@@ -1486,6 +1496,11 @@ class GreedyOptimizer:
         self.last_changed: Optional[Tuple[str, ...]] = None
         self.delta_solves = 0
         self.full_solves = 0
+        # Row-form delta solves: allocation rows taken by reference from
+        # the previous allocation (or the shared zeros row), and rows copied
+        # out of the state for the placement schedule.
+        self.rows_shared = 0
+        self.rows_copied = 0
         # Futile top-up memo: app_id -> (state.epoch, target) of a delta
         # placement attempt that could not reach its target. Free capacity
         # only shrinks while the epoch is unchanged, so the retry is
@@ -1665,7 +1680,7 @@ class GreedyOptimizer:
             if soa and n_prev and prev.app_ids == app_ids[:n_prev]:
                 k_prefix = n_prev
             elif prev is not None:
-                prev_map = dict(zip(prev.app_ids, prev.x))
+                prev_map = dict(zip(prev.app_ids, prev.rows))
             else:
                 prev_map = {}
 
@@ -1674,7 +1689,8 @@ class GreedyOptimizer:
                     else app_ids[i] in prev_map
 
             def prev_row(i: int) -> np.ndarray:
-                return prev.x[i] if prev_map is None else prev_map[app_ids[i]]
+                return prev.row_at(i) if prev_map is None \
+                    else prev_map[app_ids[i]]
 
             delta = bool(self.cfg.incremental and fast and n_prev
                          and (prev_map is None
@@ -1758,6 +1774,12 @@ class GreedyOptimizer:
             inv_cap = 1.0 / np.maximum(cap, 1e-9)
             # Indices changed vs prev rows.
             changed_track: Optional[set] = None
+            # Row form (delta solve on the state): the new allocation's rows
+            # are prev's row objects, the shared zeros row for new apps, and
+            # fresh rows for the apps the schedule grants to -- no (n, b)
+            # matrix is built.
+            rows: Optional[List[np.ndarray]] = None
+            fresh: set = set()
             if delta:
                 # Delta warm start: every surviving app keeps its previous row
                 # verbatim (the stickiness loop below would reproduce exactly
@@ -1768,21 +1790,25 @@ class GreedyOptimizer:
                 # engine must fall back to the row compare.
                 changed_track = set() if soa else None
                 if state is not None:
-                    # The state's rows ARE the previous allocation: one gather
-                    # for x, one copy of the incrementally-maintained free
-                    # matrix -- no per-app row loop, no (b, n) @ (n, m) matmul.
-                    x = state.x[idx]                # fancy index -> fresh copy
+                    # The state's rows, counts and free matrix ARE the previous
+                    # allocation's: rows are read only for the scheduled apps.
+                    x = None
+                    if prev_map is None:
+                        rows = list(prev.rows)
+                        rows += [_zero_row(b)] * (n - k_prefix)
+                    else:
+                        rows = [prev_map.get(a, _zero_row(b)) for a in app_ids]
                     if integral:
                         free = state.free.copy()
                     else:
-                        # Fractional demands: derive free canonically from x
-                        # (one order-independent matmul). The full path below
-                        # canonicalizes its free the same way after the
+                        # Fractional demands: derive free canonically from the
+                        # rows (one order-independent matmul). The full path
+                        # below canonicalizes its free the same way after the
                         # stickiness loop, so both paths feed the best-fit
                         # scatter bit-identical scores -- for integral demands
                         # the incrementally-maintained matrix already IS that
                         # value exactly, and the copy is cheaper.
-                        free = cap - x.T.astype(np.float64) @ d
+                        free = cap - state.x[idx].T.astype(np.float64) @ d
                     sums = state.counts[idx].copy()
                 else:
                     x = np.zeros((n, b), dtype=np.int64)
@@ -1804,7 +1830,7 @@ class GreedyOptimizer:
                 # min(prev_j, max q: q*d <= free_j + eps), capped cumulatively.
                 for i, a in enumerate(app_ids):
                     if prev_map is None:
-                        pr = prev.x[i] if i < k_prefix else None
+                        pr = prev.row_at(i) if i < k_prefix else None
                     else:
                         pr = prev_map.get(a)
                     if pr is None or target[i] <= 0:
@@ -1870,8 +1896,26 @@ class GreedyOptimizer:
                     pass2.append(i)
                 schedule = [(i, int(nmin_v[i])) for i in pass1] \
                     + [(i, int(target[i])) for i in pass2]
-                grants = place_be.place_run(x, free, d, inv_cap, schedule) \
-                    if schedule else []
+                if not schedule:
+                    grants = []
+                elif rows is None:
+                    grants = place_be.place_run(x, free, d, inv_cap, schedule)
+                else:
+                    # Place on a (K_u, b) copy of the scheduled apps' state
+                    # rows, items remapped to its local indices; each app
+                    # granted something gets its own frozen new row.
+                    uniq = sorted({i for i, _ in schedule})
+                    local = {i: k for k, i in enumerate(uniq)}
+                    xs = state.x[idx[uniq]]
+                    grants = place_be.place_run(
+                        xs, free, d[uniq], inv_cap,
+                        [(local[i], lim) for i, lim in schedule])
+                    self.rows_copied += len(uniq)
+                    xs.flags.writeable = False
+                    for (i, _), got in zip(schedule, grants):
+                        if got:
+                            rows[i] = xs[local[i]]
+                            fresh.add(i)
                 # Replay the sequential bookkeeping over the fused results:
                 # per-app row sums, changed-row tracking, the below-n_min
                 # infeasibility abort and the futile-top-up memo updates stop
@@ -1939,7 +1983,12 @@ class GreedyOptimizer:
                 changed.sort(key=lambda i: util_w[i] * (sums[i]
                                                         - prev_row(i).sum()))
                 if len(changed) > budget_r:
-                    used = x.T.astype(np.float64) @ d       # (b, m)
+                    if rows is not None and integral:
+                        # Exact: integer counts and demands.
+                        used = cap - free                   # (b, m)
+                    else:
+                        xd = x if rows is None else np.stack(rows)
+                        used = xd.T.astype(np.float64) @ d
                     while len(changed) > budget_r:
                         reverted = False
                         for pos_i in range(len(changed) - 1, -1, -1):
@@ -1951,11 +2000,16 @@ class GreedyOptimizer:
                                 # (Resize event): the old row is no longer a
                                 # legal state to revert to.
                                 continue
-                            delta_u = (pr - x[i]).astype(np.float64)[:, None] \
+                            cur = x[i] if rows is None else rows[i]
+                            delta_u = (pr - cur).astype(np.float64)[:, None] \
                                 * d[i][None, :]
                             if np.all(used + delta_u <= cap + 1e-6):
                                 used += delta_u
-                                x[i] = pr
+                                if rows is None:
+                                    x[i] = pr
+                                else:
+                                    rows[i] = pr
+                                    fresh.discard(i)
                                 sums[i] = pr_n
                                 changed.pop(pos_i)
                                 reverted = True
@@ -1975,6 +2029,11 @@ class GreedyOptimizer:
                 self.last_changed = ()
 
             if delta:
+                if rows is None:
+                    alloc = Allocation.trusted(app_ids, x)
+                else:
+                    self.rows_shared += n - len(fresh)
+                    alloc = Allocation.from_rows(app_ids, tuple(rows), b)
                 if integral:
                     # Provably feasible, skip the O(n*b) re-validation: rows
                     # start from the (validated) previous allocation, every
@@ -1983,11 +2042,10 @@ class GreedyOptimizer:
                     # [n_min, target <= n_max]. The legacy engine still
                     # validates, so the engine bit-exactness tests cross-check
                     # this proof.
-                    return Allocation.trusted(app_ids, x)
+                    return alloc
                 # Fractional demands: the free matrix carries rounding, so the
                 # feasibility proof is only epsilon-exact -- keep the cheap
                 # trusted construction but run the full capacity/bounds check.
-                alloc = Allocation.trusted(app_ids, x)
                 validate_allocation(alloc, apps, cluster, d=d)
                 return alloc
             alloc = Allocation(app_ids, x)

@@ -166,7 +166,7 @@ class _MergedAllocation:
         i = self.app_ids.index(app_id)
         for cols, alloc in self._parts:
             if i < len(alloc.app_ids):
-                return int(alloc.x[i].sum())
+                return int(alloc.row_at(i).sum())
             i -= len(alloc.app_ids)
         return 0
 

@@ -235,7 +235,7 @@ class ReferenceClusterSimulator(_SimulatorBase):
         # container counts
         counts = {a: 0 for a in active}
         for i, app_id in enumerate(res.allocation.app_ids):
-            counts[app_id] = int(res.allocation.x[i].sum())
+            counts[app_id] = int(res.allocation.row_at(i).sum())
         for a, rt in active.items():
             rt.containers = counts.get(a, 0)
             if rt.containers > 0 and rt.started_at is None:

@@ -178,39 +178,108 @@ class ClusterSpec:
         )
 
 
-@dataclasses.dataclass
 class Allocation:
-    """x[i, j]: containers of application i on slave j (paper Table I)."""
+    """x[i, j]: containers of application i on slave j (paper Table I).
 
-    app_ids: Tuple[str, ...]
-    x: np.ndarray  # (n_apps, b) non-negative ints
+    Two forms hold the same numbers. The dense form keeps the (n_apps, b)
+    int64 matrix `x`; its `rows` are read-only views of it. The row form
+    (`from_rows`) keeps one read-only 1-D int64 row per app, shared by
+    reference with the allocations it was derived from, so an app whose
+    placement did not change costs a pointer instead of b copied counts.
+    Reading `x` on a row form stacks the rows once and caches the matrix,
+    read-only; the class counter `densified` counts those stackings (the
+    master's delta path never moves it). Rows are never written."""
 
-    def __post_init__(self):
-        self.x = np.asarray(self.x, dtype=np.int64)
-        if self.x.shape[0] != len(self.app_ids):
+    __slots__ = ("app_ids", "_x", "_rows")
+    densified = 0
+
+    def __init__(self, app_ids: Tuple[str, ...], x: np.ndarray):
+        x = np.asarray(x, dtype=np.int64)
+        if x.shape[0] != len(app_ids):
             raise ValueError("x rows must match app_ids")
-        if (self.x < 0).any():
+        if (x < 0).any():
             raise ValueError("allocations must be non-negative")
+        self.app_ids = app_ids
+        self._x = x
+        self._rows: Optional[Tuple[np.ndarray, ...]] = None
 
     @classmethod
     def trusted(cls, app_ids: Tuple[str, ...], x: np.ndarray) -> "Allocation":
-        """Construct without the __post_init__ scans, for hot paths whose
+        """Construct without the __init__ scans, for hot paths whose
         `x` is already a non-negative int64 matrix (rows gathered from a
         validated allocation or the SoA state). The full-matrix negativity
         scan costs O(n*b) per event at cluster scale."""
         out = cls.__new__(cls)
         out.app_ids = app_ids
-        out.x = x
+        out._x = x
+        out._rows = None
         return out
 
+    @classmethod
+    def from_rows(cls, app_ids: Tuple[str, ...],
+                  rows: Tuple[np.ndarray, ...],
+                  b: Optional[int] = None) -> "Allocation":
+        """Row form, trusted like `trusted`: `rows` holds one read-only
+        non-negative int64 row of length b per app, kept by reference.
+        `b`, the slave count, is used only when there are no rows."""
+        if len(rows) != len(app_ids):
+            raise ValueError("rows must match app_ids")
+        if not rows and b is None:
+            raise ValueError("an allocation with no rows needs b")
+        out = cls.__new__(cls)
+        out.app_ids = app_ids
+        out._rows = rows
+        out._x = None if rows else np.zeros((0, b), np.int64)
+        return out
+
+    @property
+    def x(self) -> np.ndarray:
+        if self._x is None:
+            x = np.stack(self._rows)
+            x.flags.writeable = False
+            self._x = x
+            Allocation.densified += 1
+        return self._x
+
+    @property
+    def rows(self) -> Tuple[np.ndarray, ...]:
+        if self._rows is None:
+            view = self._x.view()
+            view.flags.writeable = False
+            self._rows = tuple(view)
+        return self._rows
+
+    @property
+    def b(self) -> int:
+        return self._x.shape[1] if self._x is not None else \
+            self._rows[0].shape[0]
+
+    def row_at(self, i: int) -> np.ndarray:
+        """App i's row, read-only, without stacking a row form."""
+        if self._rows is not None:
+            return self._rows[i]
+        row = self._x[i]
+        row.flags.writeable = False
+        return row
+
+    def take(self, keep: Sequence[int]) -> "Allocation":
+        """The apps at positions `keep`, in that order, sharing their
+        rows with this allocation."""
+        rows = self.rows
+        return Allocation.from_rows(tuple(self.app_ids[i] for i in keep),
+                                    tuple(rows[i] for i in keep), self.b)
+
     def containers_of(self, app_id: str) -> int:
-        return int(self.x[self.app_ids.index(app_id)].sum())
+        return int(self.row(app_id).sum())
 
     def row(self, app_id: str) -> np.ndarray:
-        return self.x[self.app_ids.index(app_id)]
+        return self.row_at(self.app_ids.index(app_id))
 
     def as_dict(self) -> Dict[str, np.ndarray]:
-        return {a: self.x[i].copy() for i, a in enumerate(self.app_ids)}
+        return {a: self.row_at(i).copy() for i, a in enumerate(self.app_ids)}
+
+    def __repr__(self) -> str:
+        return f"Allocation(app_ids={self.app_ids!r}, x={self.x!r})"
 
     @staticmethod
     def empty(app_ids: Sequence[str], b: int) -> "Allocation":

@@ -28,6 +28,7 @@ from repro.core import (ApplicationSpec, ClusterSpec, DormMaster,
                         JaxBackend, OptimizerConfig, RecordingProtocol,
                         ResourceVector, configure_compile_cache, get_backend)
 from repro.core.telemetry import compile_counter
+from repro.core.types import Allocation
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -228,6 +229,41 @@ else:                                                  # pragma: no cover
     def test_master_storms_bit_exact_across_backends(chunk):
         for k in range(2):
             _check_master_storm(chunk * 2 + k)
+
+
+# ------------------------------------------ row-form delta with a revert
+
+@pytest.mark.parametrize("grow", [("a0", "a1"), ("a0", "a1", "a2")])
+def test_row_form_delta_revert_bit_exact_across_backends(grow):
+    """A flood of relaxing resizes on the delta path: every grown app gets
+    a top-up, Eq 16's budget (ceil(0.2 * 3) = 1 row) reverts all but one,
+    and the reverted rows are the previous allocation's row objects. Both
+    backends return the same rows, and the row form stacks to the dense
+    matrix."""
+    cluster = ClusterSpec.homogeneous(4, ResourceVector.of(8, 0, 32))
+    specs = [ApplicationSpec(f"a{i}", "x", ResourceVector.of(1, 0, 2), 1,
+                             2, 1) for i in range(3)]
+    out = {}
+    for be in ("numpy", "jax"):
+        cfg = OptimizerConfig(0.2, 0.2, incremental=True, soa=True,
+                              backend=be)
+        m = DormMaster(cluster, "greedy", cfg, protocol=RecordingProtocol())
+        m.on_arrival(specs[:2])
+        m.on_arrival(specs[2:])                    # row form from here on
+        prev = m.prev_alloc
+        res = m.on_batch((), [(a, None, 4) for a in grow], ())
+        alloc, o = res.allocation, m.optimizer
+        assert o.delta_solves == 2 and o.full_solves == 1, be
+        assert len(res.adjusted_app_ids) == 1, be
+        same = [alloc.row_at(i) is prev.row_at(i) for i in range(3)]
+        assert same.count(True) == 2, (be, same)
+        dense = np.stack([alloc.row_at(i) for i in range(3)])
+        np.testing.assert_array_equal(
+            Allocation.from_rows(alloc.app_ids, alloc.rows).x, dense)
+        out[be] = (alloc.app_ids, alloc.x, res.adjusted_app_ids)
+    assert out["jax"][0] == out["numpy"][0]
+    np.testing.assert_array_equal(out["jax"][1], out["numpy"][1])
+    assert out["jax"][2] == out["numpy"][2]
 
 
 def test_jax_backend_books_compile_time():
