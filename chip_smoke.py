@@ -5,15 +5,15 @@ Default run (one chip): the 5000-slave x 2000-app deployment of
 `generate_trace`, seed 0, 30 s mean inter-arrival, 24 h horizon) goes
 through `ClusterRuntime` with the storm absorber on (60 s window, as the
 replay benchmark runs it) and a `DormMaster` whose optimizer runs on
-`OptimizerConfig(backend="jax")`; on a TPU that engine places containers
-with the compiled Pallas best-fit kernel. The same trace then goes through
+`OptimizerConfig(backend="jax")`, which places containers with the
+compiled best-fit sort fill. The same trace then goes through
 the numpy engine, the reference. The run fails unless:
 
   * every app completes on both engines,
   * every applied allocation respects capacity and n_min <= count <= n_max,
   * the jax engine's timeline equals the numpy engine's, event for event,
-  * the compiled `place_run` program holds the Pallas kernel
-    (`tpu_custom_call`).
+  * the compiled `place_run` program holds the sort fill (an XLA `sort`)
+    and no custom kernel (`tpu_custom_call`).
 
 `--four-chips` runs only the live-partition path across four chips: a
 `DormMaster` drives two `ElasticTrainer`s through `ElasticJaxProtocol`;
@@ -33,6 +33,7 @@ import argparse
 import hashlib
 import json
 import os
+import re
 import sys
 import tempfile
 import time
@@ -111,10 +112,9 @@ def scheduler_phase(n_slaves: int = 5000, n_apps: int = 2000,
               f"wall {r['wall_s']:.3f} s (context only)", flush=True)
     be = runs["jax"]["backend"]
     compiles = compile_counter()
-    print(f"jax engine: Pallas placement kernel "
-          f"{'on' if be.use_pallas else 'off'}; compile "
-          f"{be.compile_s:.3f} s ({compiles.cache_hits} program(s) loaded "
-          f"from the persistent cache)", flush=True)
+    print(f"jax engine: compile {be.compile_s:.3f} s "
+          f"({compiles.cache_hits} program(s) loaded from the persistent "
+          f"cache)", flush=True)
     for name in sorted(compiles.count):
         print(f"  compile {name}: {compiles.count[name]} program(s), "
               f"{compiles.seconds[name]:.3f} s", flush=True)
@@ -141,11 +141,13 @@ def scheduler_phase(n_slaves: int = 5000, n_apps: int = 2000,
     if "dorm.place_run" not in compiles.count:
         failures.append("place_run never ran on the jax engine")
     else:
-        has_kernel = "tpu_custom_call" in be.compiled_text("place_run")
-        print(f"compiled place_run holds tpu_custom_call: {has_kernel}",
+        text = be.compiled_text("place_run")
+        ok = (re.search(r"\) sort\(|\} sort\(", text) is not None
+              and "tpu_custom_call" not in text)
+        print(f"compiled place_run holds a sort, no custom call: {ok}",
               flush=True)
-        if not has_kernel:
-            failures.append("compiled place_run has no tpu_custom_call")
+        if not ok:
+            failures.append("compiled place_run is not the sort fill")
     return failures
 
 

@@ -33,10 +33,10 @@ per program name and reported as
 every event the whole pass that decided it, first-touch compiles
 included, and reports the compile seconds beside it.
 
-On a TPU the placement inner loop is the compiled Pallas kernel
-(`repro.kernels.placement.best_fit_counts`, a sort-free O(b^2)
-rank-compare reduction); on other platforms it is the `lax` argsort
-composition. jax is required: this example does not fall back to numpy.
+The placement inner loop is `repro.kernels.placement.best_fit_counts`
+on every platform: one stable `lax.sort` of the score's exact f32 parts,
+an exclusive cumsum and a second sort back to slave order, O(b log^2 b).
+jax is required: this example does not fall back to numpy.
 
 Run:  PYTHONPATH=src python examples/jax_backend.py [--slaves 120 --apps 60]
 """

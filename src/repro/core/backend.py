@@ -11,12 +11,12 @@ explicit seam under them:
     by construction. It stays the bit-exactness reference, exactly like
     `ReferenceClusterSimulator` does for the simulator.
   * `JaxBackend`    -- the same three kernels as `jax.jit` programs built
-    on `lax` (stable argsort + clipped-cumsum scatter, `lax.scan` for the
+    on `lax` (stable sort + clipped cumsum for placement, `lax.scan` for the
     inherently sequential grant loop, `lax.while_loop` for the ladder's
-    exhaustion passes). On a TPU the placement inner loop is the compiled
-    Pallas kernel in `repro.kernels.placement`; on other platforms it is
-    the lax argsort composition. jax is a hard dependency: asking for this
-    backend where jax cannot run raises.
+    exhaustion passes). The placement inner loop is the stable sort fill
+    of `repro.kernels.placement` on every platform, so the parity suite
+    on the CPU runs the chip's code. jax is a hard dependency: asking for
+    this backend where jax cannot run raises.
   * `AutoBackend`   -- `backend="auto"`: problem-size dispatch between the
     two, numpy below the measured crossover (AUTO_CROSSOVER_*), jax above.
 
@@ -364,18 +364,21 @@ def _pow2(n: int) -> int:
     return 1 << max(3, int(n - 1).bit_length()) if n > 1 else 8
 
 
-_JAX_FNS: Dict[bool, Dict[str, object]] = {}
+_JAX_FNS: Dict[str, object] = {}
 
 
-def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
-    """Build (once per process and pallas-flag) the jitted kernel programs.
+def _build_jax_fns(_ignored: object = None) -> Dict[str, object]:
+    """Build (once per process) the jitted kernel programs.
 
     All float work is f64 (callers wrap invocations in `enable_x64`); the
-    Pallas kernel inside `place` orders slaves by an exact f32 split of the
-    f64 score -- see `repro.kernels.placement`."""
-    if use_pallas in _JAX_FNS:
-        return _JAX_FNS[use_pallas]
+    sort fill inside `place` orders slaves by an exact f32 split of the
+    f64 score -- see `repro.kernels.placement`. The argument is ignored:
+    it once chose the placement kernel, and `bench/rehearse.py` still
+    passes it."""
+    if _JAX_FNS:
+        return _JAX_FNS
     jax, jnp, lax, _ = _jax_modules()
+    from ..kernels.placement import best_fit_counts
 
     def rounded(prod):
         """`prod` rounded on its own before it meets an add, as numpy
@@ -398,10 +401,12 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
         (score, index) key sorts first receives the whole batch from the
         clipped cumsum too), and clipping q at `need` before the cumsum
         never changes diff(min(cumsum, need)) while keeping the int64 sums
-        small enough for the Pallas kernel's int32 accumulators. `need_i`
-        may be 0 (a no-op schedule entry inside `place_run`): every q is
-        then clipped to 0 and no slave is granted."""
-        b, m = free.shape
+        small enough for the sort fill's int32 cumsum. `need_i` may be 0
+        (a no-op schedule entry inside `place_run`): every q is then
+        clipped to 0 and no slave is granted. The fill is one stable sort
+        of the score's exact f32 parts (`kernels.placement`) on every
+        platform."""
+        m = free.shape[1]
         need_f = need_i.astype(free.dtype)
         # Per-resource ops are unrolled over the static m (<= 8 in this
         # repo), keeping numpy's left-to-right pairwise order bit-for-bit.
@@ -423,16 +428,9 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
         for k in range(1, m):
             score = score + rounded((free[:, k] - di[k]) * inv_cap[:, k])
         masked = jnp.where(fit, score, jnp.inf)
-        if use_pallas:
-            from ..kernels.placement import best_fit_counts
-            counts = best_fit_counts(masked, qn.astype(jnp.int32),
-                                     need_i.astype(jnp.int32),
-                                     interpret=False)
-            return counts.astype(jnp.int64)
-        order = jnp.argsort(masked, stable=True)    # ties -> lowest index
-        csum = jnp.minimum(jnp.cumsum(qn[order]), need_i)
-        counts = csum - jnp.concatenate([jnp.zeros(1, jnp.int64), csum[:-1]])
-        return jnp.zeros(b, jnp.int64).at[order].set(counts)
+        counts = best_fit_counts(masked, qn.astype(jnp.int32),
+                                 need_i.astype(jnp.int32))
+        return counts.astype(jnp.int64)
 
     @jax.jit
     def place(free, di, inv_cap, need):
@@ -444,8 +442,8 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
         """Fused multi-app placement: ONE device program executes a whole
         (app, limit) placement schedule -- the per-app `place` body inside
         a lax.scan carrying the free-capacity matrix -- so the host
-        dispatches once per SOLVE instead of once per app (and on TPUs the
-        Pallas placement kernel runs inside this single program).
+        dispatches once per SOLVE instead of once per app (the sort fill
+        runs inside this single program).
 
         Schedule entry k: demand row d_items[k], count limit lims[k], the
         app's container total before this run bases[k], and aslots[k] = the
@@ -565,35 +563,25 @@ def _build_jax_fns(use_pallas: bool) -> Dict[str, object]:
         with jax.named_scope("dorm.ladder"):
             return ladder_fill(d, n_min, n_max, w, valid, total, levels)
 
-    _JAX_FNS[use_pallas] = {"probe": probe, "place": place,
-                            "place_run": place_run, "ladder": ladder}
-    return _JAX_FNS[use_pallas]
+    _JAX_FNS.update(probe=probe, place=place, place_run=place_run,
+                    ladder=ladder)
+    return _JAX_FNS
 
 
 class JaxBackend(Backend):
     """jax.jit backend; see the module docstring for the padding contract.
 
-    The placement inner loop is the compiled Pallas kernel on a TPU and the
-    lax argsort composition on any other platform (`use_pallas=None`).
-    `use_pallas=True` off a TPU raises: the kernel is never interpreted
-    here. `use_pallas` records which of the two runs."""
+    The placement inner loop is the stable sort fill of
+    `repro.kernels.placement` on every platform: the programs the CPU
+    runs are the ones the chip runs."""
 
     name = "jax"
 
-    def __init__(self, use_pallas: Optional[bool] = None,
-                 spans: Optional[Spans] = None):
+    def __init__(self, spans: Optional[Spans] = None):
         jax, jnp, _, enable_x64 = _jax_modules()
-        on_tpu = jax.default_backend() == "tpu"
-        if use_pallas is None:
-            use_pallas = on_tpu
-        if use_pallas and not on_tpu:
-            raise RuntimeError(
-                "the Pallas placement kernel needs a TPU; jax's default "
-                f"backend is {jax.default_backend()!r}")
-        self.use_pallas = bool(use_pallas)
         self._jax, self._jnp = jax, jnp
         self._x64 = enable_x64
-        self._fns = _build_jax_fns(self.use_pallas)
+        self._fns = _build_jax_fns()
         self.spans = spans if spans is not None else Spans()
         self._compiles = compile_counter()
         # tag -> [(shape, dtype)] of the first call: what `compiled_text`
@@ -623,7 +611,7 @@ class JaxBackend(Backend):
 
     def compiled_text(self, tag: str) -> str:
         """Optimized HLO of kernel `tag` at the first padded shapes it ran
-        with (e.g. to check that `place_run` holds the Pallas kernel)."""
+        with (e.g. to check that `place_run` holds the sort)."""
         jax, jnp = self._jax, self._jnp
         with self._x64():
             specs = [jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))

@@ -140,8 +140,8 @@ class OptimizerConfig:
     colgen_pack_vars: int = 20_000
     colgen_pack_rounds: int = 3
     # Array backend for the greedy solver's hot kernels (PR 6): "numpy"
-    # (the bit-exactness reference) or "jax" (jit/lax programs, Pallas
-    # placement inner loop on TPU -- see core.backend). The env default
+    # (the bit-exactness reference) or "jax" (jit/lax programs, a stable
+    # sort fill for placement -- see core.backend). The env default
     # lets CI run the whole tier-1 suite on the jax backend without code
     # changes (REPRO_BACKEND=jax).
     backend: str = dataclasses.field(

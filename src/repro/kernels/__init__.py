@@ -2,6 +2,8 @@
 
 Layout: <name>.py = pl.pallas_call + BlockSpec; ops.py = jit'd wrappers
 adapting model layouts; ref.py = pure-jnp ground truth used in tests.
+The scheduler's best-fit fill (placement.py) is a plain `lax.sort`
+program, not a Pallas kernel.
 """
 from . import ops, ref
 from .flash_attention import flash_attention_gqa

@@ -1,4 +1,4 @@
-"""Best-fit placement inner loop as a Pallas TPU kernel.
+"""Best-fit placement inner loop as one stable sort and a prefix sum.
 
 The scheduler's batched best-fit scatter grants `need` containers of one
 app across slaves in ascending (score, slave index) order, each slave
@@ -7,88 +7,48 @@ capped at its max feasible count q_j:
     order = argsort(score)            # stable
     counts[order] = diff(min(cumsum(q[order]), need))
 
-A sort is an awkward TPU primitive, but the same result has a sort-free
-closed form: slave j's position in the fill order is determined by the
-total q of slaves that strictly precede it,
+Equivalently, slave j takes what is left of `need` once every slave
+before it in that order has taken its q:
 
-    before_j = sum_k q_k * [(score_k, k) < (score_j, j)]      (lexicographic)
+    before_j = exclusive cumsum of q in (score, index) order
     counts_j = clip(need - before_j, 0, q_j)
 
-(b_j = min(cumsum) prefix available when j is reached; each slave takes
-min(q_j, what's left)). That is an O(b^2) masked reduction -- a natural
-(J, K) Pallas grid of rank-compare tiles with an accumulate-then-epilogue
-pattern (same shape as the moe_gemm kernel's K loop), and for the
-scheduler's b it is far below the flops the MXU wastes on a sort.
+`best_fit_counts` computes exactly this: one stable `lax.sort` of the
+score's key parts carrying q and the slave index along, an int32
+exclusive cumsum of the sorted q, the clip, and a second sort keyed on
+the slave index back to slave order. The work is two sort networks,
+O(b log^2 b), on every platform.
 
 Contract (enforced by the caller, `repro.core.backend.JaxBackend`):
   * q int32, pre-clipped to [0, need]; infeasible slaves carry q = 0 (their
-    score may be +inf). int32 accumulation then never overflows for
+    score may be +inf). The int32 cumsum then never overflows for
     b * need < 2^31.
-  * score f32 or f64. The kernel itself compares f32 only: an f64 score is
-    split outside the kernel into three f32 parts (hi, mid, lo) whose
-    unevaluated sum is the score exactly, and the kernel compares them
-    lexicographically. That order equals the f64 order for every finite
-    score (IEEE f64 needs all three parts; the TPU's f64, a pair of f32,
-    needs two), so two distinct f64 scores never merge into one tie.
+  * score f32 or f64. The sort compares 32-bit keys: an f64 score is split
+    into three f32 parts (hi, mid, lo) whose unevaluated sum is the score
+    exactly, and the sort orders them lexicographically. That order
+    equals the f64 order for every finite score (IEEE f64 needs all three
+    parts; the TPU's f64, a pair of f32, needs two), so two distinct f64
+    scores never merge into one tie. Each part is sorted as an int32 of
+    the same order (`_order_key`), -0.0 and +0.0 mapped to one value, and
+    the sort is stable: equal scores keep slave order, as in numpy's
+    stable argsort.
 
 `best_fit_counts_ref` is the pure-jnp oracle (the argsort/cumfill
 composition itself).
 
-Invocation context: `JaxBackend` calls this kernel both per item
+Invocation context: `JaxBackend` calls this fill both per item
 (`place_batch`) and from inside the fused multi-app placement program
 (`place_run`, one jit'd `lax.scan` over the whole batch's schedule).
-Inside the scan the kernel is traced ONCE per padded (b,) bucket and
-replayed for every scan step, so it must stay free of per-item host
-logic -- everything item-specific (need, scores, q) arrives as traced
-operands.
+Inside the scan it is traced ONCE per padded (b,) bucket and replayed for
+every scan step, so it must stay free of per-item host logic --
+everything item-specific (need, scores, q) arrives as traced operands.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.experimental import pallas as pl
 
 _PARTS = 3
-KERNEL_NAME = "dorm_best_fit"
-# Block indices stay int32 under jax_enable_x64 (Mosaic rejects i64 ones).
-_I0 = np.int32(0)
-
-
-def _placement_kernel(key_j_ref, key_k_ref, q_k_ref, q_j_ref, need_ref,
-                      out_ref, *, block: int):
-    k = pl.program_id(1)
-    nk = pl.num_programs(1)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    kj = key_j_ref[...]                                    # (PARTS, B)
-    kk = key_k_ref[...]                                    # (PARTS, B)
-    qk = q_k_ref[...].reshape(block, 1)                    # (B, 1)
-    jidx = (pl.program_id(0) * block
-            + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1))
-    kidx = (k * block
-            + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0))
-    # (B_k, B_j) strict-predecessor mask: lexicographic over the key parts
-    # (most significant first), ties broken by slave index.
-    precedes = kidx < jidx
-    for p in reversed(range(_PARTS)):
-        sj = kj[p:p + 1, :]                                # (1, B)
-        sk = kk[p:p + 1, :].reshape(block, 1)              # (B, 1)
-        precedes = (sk < sj) | ((sk == sj) & precedes)
-    out_ref[...] += jnp.sum(
-        jnp.where(precedes, qk, jnp.int32(0)), axis=0, dtype=jnp.int32,
-    ).reshape(1, block)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        need = need_ref[0, 0]
-        before = out_ref[...]
-        out_ref[...] = jnp.clip(need - before, jnp.int32(0), q_j_ref[...])
 
 
 def split_key(score: jnp.ndarray) -> jnp.ndarray:
@@ -106,38 +66,31 @@ def split_key(score: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack(parts)
 
 
-def best_fit_counts(score: jnp.ndarray, q: jnp.ndarray, need: jnp.ndarray,
-                    *, block: int = 256,
-                    interpret: bool = False) -> jnp.ndarray:
-    """score (b,), q (b,) int32 in [0, need], need () int32 -> counts (b,).
+def _order_key(part: jnp.ndarray) -> jnp.ndarray:
+    """f32 (no NaN) -> int32 in the same order, with -0.0 equal to +0.0.
 
-    Compiled for the TPU unless the caller passes `interpret=True` (the
-    tests do, to pin the kernel against the oracle on CPU)."""
+    The sort then compares plain integers: float keys cost a NaN and zero
+    canonicalization per compare, which doubles the v5e compile time of
+    an 8192-slave sort."""
+    i = jax.lax.bitcast_convert_type(part, jnp.int32)
+    flipped = i ^ ((i >> 31) & jnp.int32(0x7FFFFFFF))   # negatives reversed
+    return jnp.where(i == jnp.int32(-2 ** 31), jnp.int32(0), flipped)
+
+
+def best_fit_counts(score: jnp.ndarray, q: jnp.ndarray,
+                    need: jnp.ndarray) -> jnp.ndarray:
+    """score (b,), q (b,) int32 in [0, need], need () int32 -> counts (b,)
+    int32, slave j's grant in slave order."""
     b = score.shape[0]
-    bb = min(block, b)
-    if b % bb:
-        raise ValueError(f"slaves {b} must divide block {bb}")
-    grid = (b // bb, b // bb)
-    key = split_key(score)
-    q2 = q.astype(jnp.int32).reshape(1, b)
-    need2 = need.astype(jnp.int32).reshape(1, 1)
-    out = pl.pallas_call(
-        functools.partial(_placement_kernel, block=bb),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((_PARTS, bb), lambda j, k: (_I0, j)),   # key, j tile
-            pl.BlockSpec((_PARTS, bb), lambda j, k: (_I0, k)),   # key, k tile
-            pl.BlockSpec((1, bb), lambda j, k: (_I0, k)),        # q, k tile
-            pl.BlockSpec((1, bb), lambda j, k: (_I0, j)),        # q, j tile
-            pl.BlockSpec((1, 1), lambda j, k: (_I0, _I0)),       # need
-        ],
-        out_specs=pl.BlockSpec((1, bb), lambda j, k: (_I0, j)),
-        out_shape=jax.ShapeDtypeStruct((1, b), jnp.int32),
-        interpret=interpret,
-        # A stable name for the kernel in compiled text and device traces.
-        name=KERNEL_NAME,
-    )(key, key, q2, q2, need2)
-    return out.reshape(b)
+    keys = [_order_key(part) for part in split_key(score)]
+    slave = jax.lax.iota(jnp.int32, b)
+    *_, q_s, order = jax.lax.sort((*keys, q.astype(jnp.int32), slave),
+                                  num_keys=_PARTS, is_stable=True)
+    before = jnp.cumsum(q_s) - q_s
+    counts_s = jnp.clip(need.astype(jnp.int32) - before, 0, q_s)
+    # Back to slave order by a second sort keyed on the permutation: on a
+    # v5e it costs less than a scatter (PERF.md).
+    return jax.lax.sort((order, counts_s), num_keys=1)[1]
 
 
 def best_fit_counts_ref(score: jnp.ndarray, q: jnp.ndarray,

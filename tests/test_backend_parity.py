@@ -25,7 +25,7 @@ import numpy as np
 import pytest
 
 from repro.core import (ApplicationSpec, ClusterSpec, DormMaster,
-                        JaxBackend, OptimizerConfig, RecordingProtocol,
+                        OptimizerConfig, RecordingProtocol,
                         ResourceVector, configure_compile_cache, get_backend)
 from repro.core.telemetry import compile_counter
 from repro.core.types import Allocation
@@ -288,12 +288,44 @@ def test_jax_backend_books_compile_time():
     assert m.phase_breakdown()["backend_compile"] == m.backend_compile_s
 
 
-def test_pallas_kernel_needs_a_tpu():
-    """Off a TPU the engine runs the lax path; asking for the Pallas
-    kernel there raises instead of running its interpreter."""
-    assert JaxBackend().use_pallas is False
-    with pytest.raises(RuntimeError, match="TPU"):
-        JaxBackend(use_pallas=True)
+# K = 3 and 8: app 0 comes back for its target after its n_min, as the
+# optimizer's two best-fit passes schedule it.
+SCHEDULES = {
+    1: [(0, 700)],
+    3: [(0, 40), (1, 300), (0, 900)],
+    8: [(2, 5), (0, 60), (1, 120), (3, 2000), (0, 400), (4, 1),
+        (2, 5000), (1, 250)],
+}
+
+
+@pytest.mark.parametrize("k", sorted(SCHEDULES))
+def test_place_run_bit_exact_at_8192_padded_slaves(k):
+    """The sort fill inside `place_run` over 5000 slaves (padded to 8192,
+    as in the 5000-slave deployment) grants what the numpy engine grants,
+    bit for bit: the testbed's 20 slave types repeated, so scores tie in
+    large groups, with integral and fractional demands."""
+    rng = np.random.default_rng(k)
+    b, m = 5000, 3
+    kinds = np.array([[12, 1, 128]] * 5 + [[12, 0, 128]] * 15, np.float64)
+    cap = kinds[np.arange(b) % 20]
+    used = np.floor(cap * rng.uniform(0.0, 0.9, size=(b, m)))
+    used[rng.random(b) < 0.5] = 0.0            # half the slaves stay empty
+    free = cap - used
+    inv_cap = np.where(cap > 0, 1.0 / np.maximum(cap, 1e-12), 0.0)
+    d = np.array([[1, 0, 4], [2, 1, 16], [0.5, 0, 2.5], [4, 0, 8],
+                  [1.25, 0, 3.5]], np.float64)
+    x = np.zeros((d.shape[0], b), np.int64)
+    x[1, :3] = 1                               # app 1 starts with 3
+    items = SCHEDULES[k]
+    np_be, jx_be = _backends()
+    x_r, free_r = x.copy(), free.copy()
+    x_g, free_g = x.copy(), free.copy()
+    ref = np_be.place_run(x_r, free_r, d, inv_cap, items)
+    got = jx_be.place_run(x_g, free_g, d, inv_cap, items)
+    assert got == ref
+    assert sum(ref) > 0
+    np.testing.assert_array_equal(x_g, x_r)
+    np.testing.assert_array_equal(free_g, free_r)
 
 
 def test_compile_cache_location(monkeypatch, tmp_path):

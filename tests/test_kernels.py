@@ -155,7 +155,24 @@ def test_ops_pallas_impl_needs_a_tpu():
 
 # -------------------------------------------------- placement (scheduler)
 
-PLACE_SIZES = [8, 32, 256, 512]
+PLACE_SIZES = [8, 32, 256, 512, 1024, 8192]
+
+
+def _np_fill(score, q, need):
+    """numpy's stable argsort fill, the engine's reference order."""
+    order = np.argsort(score, kind="stable")
+    csum = np.minimum(np.cumsum(q[order]), need)
+    counts = np.zeros_like(q)
+    counts[order] = np.diff(np.concatenate(([0], csum)))
+    return counts
+
+
+def _sort_fill(score, q, need):
+    from repro.kernels.placement import best_fit_counts
+    with jax.enable_x64():
+        return np.asarray(best_fit_counts(jnp.asarray(score),
+                                          jnp.asarray(q, jnp.int32),
+                                          jnp.int32(need)))
 
 
 @pytest.mark.parametrize("b", PLACE_SIZES)
@@ -165,7 +182,7 @@ def test_best_fit_counts_vs_oracle(b, dtype):
     with jax.enable_x64():
         rng = np.random.default_rng(b)
         for trial in range(6):
-            score = rng.uniform(0.0, 4.0, size=b)
+            score = rng.uniform(-2.0, 4.0, size=b)
             if trial % 2:                      # force ties + infeasibles
                 score = np.round(score, 1)
                 score[rng.integers(b, size=max(b // 4, 1))] = np.inf
@@ -174,15 +191,68 @@ def test_best_fit_counts_vs_oracle(b, dtype):
             need = np.int32(rng.integers(1, int(q.sum()) + 2))
             q = np.minimum(q, need).astype(np.int32)
             s = jnp.asarray(score, dtype=dtype)
-            got = best_fit_counts(s, jnp.asarray(q), jnp.asarray(need),
-                                  block=256, interpret=True)
+            got = best_fit_counts(s, jnp.asarray(q), jnp.asarray(need))
             ref = best_fit_counts_ref(s, jnp.asarray(q), jnp.asarray(need))
             np.testing.assert_array_equal(np.asarray(got), np.asarray(ref),
                                           err_msg=f"b={b} trial={trial}")
 
 
-def test_best_fit_counts_rejects_ragged_block():
-    from repro.kernels.placement import best_fit_counts
-    with pytest.raises(ValueError):
-        best_fit_counts(jnp.zeros(10), jnp.zeros(10, jnp.int32),
-                        jnp.int32(1), block=4, interpret=True)
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_best_fit_counts_signed_zeros_tie_by_slave(dtype):
+    """-0.0 and +0.0 are one score: the fill takes them in slave order, as
+    numpy's stable argsort does, whatever the sign bit."""
+    rng = np.random.default_rng(3)
+    b = 64
+    score = np.where(rng.random(b) < 0.5, -0.0, 0.0).astype(dtype)
+    score[::7] = 0.5                           # higher scores among them
+    q = np.ones(b, np.int32)
+    for need in (1, 5, 20, 40):
+        got = _sort_fill(score, q, need)
+        np.testing.assert_array_equal(got, _np_fill(score, q, need))
+        # The first `need` zero-score slaves by index, signs ignored.
+        zeros = np.flatnonzero(score == 0.0)
+        assert got[zeros[:need]].sum() == need, need
+
+
+@pytest.mark.parametrize("part", ["mid", "lo"])
+def test_best_fit_counts_orders_f64_below_the_f32_part(part):
+    """f64 scores that round to one f32 `hi` part, and for `lo` to one
+    `mid` part as well, still fill in f64 order."""
+    rng = np.random.default_rng(11)
+    b = 48
+    # Offsets of both signs, so the lower parts are negative for some.
+    if part == "mid":                          # 48 distinct scores
+        score = 1.0 + 2.0 ** -30 * (rng.permutation(b) - 24)
+    else:                                      # 8 distinct, 6 slaves each
+        score = 1.0 + 2.0 ** -25 + 2.0 ** -52 * (rng.permutation(b) % 8 - 3)
+    hi = np.float32(score)
+    assert np.all(hi == hi[0])                 # one f32 hi part
+    if part == "lo":
+        mid = np.float32(score - hi.astype(np.float64))
+        assert np.all(mid == mid[0])           # and one mid part
+    assert np.unique(score).size == (b if part == "mid" else 8)
+    q = rng.integers(1, 4, size=b).astype(np.int32)
+    for need in (1, 7, 30, int(q.sum())):
+        got = _sort_fill(score, q, need)
+        np.testing.assert_array_equal(got, _np_fill(score, q, need))
+    # The best (lowest) score takes the first grant.
+    assert _sort_fill(score, q, 1)[np.argmin(score)] == 1
+
+
+def test_best_fit_counts_need_above_capacity_grants_every_q():
+    rng = np.random.default_rng(5)
+    b = 256
+    score = rng.uniform(-1.0, 1.0, size=b)
+    score[rng.integers(b, size=40)] = np.inf
+    q = rng.integers(0, 5, size=b).astype(np.int32)
+    q[~np.isfinite(score)] = 0
+    got = _sort_fill(score, q, int(q.sum()) + 17)
+    np.testing.assert_array_equal(got, q)
+
+
+def test_best_fit_counts_all_infeasible_grants_nothing():
+    b = 128
+    score = np.full(b, np.inf)
+    q = np.zeros(b, np.int32)
+    for need in (0, 1, 1000):
+        assert not _sort_fill(score, q, need).any(), need

@@ -1,19 +1,21 @@
 """The scheduler's device programs compile for a TPU v5e, checked without a
 chip: the chip's compiler builds each program for a described (not
-attached) v5e topology. The Pallas placement kernel must come out as a
-`tpu_custom_call`, alone and inside the fused `place_run` scan, under its
-stable name.
+attached) v5e topology. The placement fill must come out as an XLA
+`sort`, alone and inside the fused `place_run` scan, with no
+`tpu_custom_call` (a Pallas kernel) in either.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.backend import _build_jax_fns
-from repro.kernels.placement import KERNEL_NAME, best_fit_counts
+from repro.kernels.placement import best_fit_counts
 
 SLAVES, RESOURCES, STEPS = 8192, 3, 64
 
@@ -40,27 +42,31 @@ def _compile(fn, one_chip, *shapes):
         return fn.lower(*args).compile().as_text()
 
 
-def test_best_fit_counts_compiles_to_tpu_kernel(one_chip):
-    fn = jax.jit(lambda s, q, n: best_fit_counts(s, q, n, interpret=False))
+def _has_sort(text):
+    return re.search(r"\) sort\(|\} sort\(", text) is not None
+
+
+def test_best_fit_counts_compiles_to_a_sort(one_chip):
+    fn = jax.jit(best_fit_counts)
     text = _compile(fn, one_chip, ((SLAVES,), jnp.float64),
                     ((SLAVES,), jnp.int32), ((), jnp.int32))
-    assert "tpu_custom_call" in text
+    assert _has_sort(text)
+    assert "tpu_custom_call" not in text
 
 
-def test_place_run_compiles_with_pallas_kernel(one_chip):
+def test_place_run_compiles_with_a_sort_and_no_custom_call(one_chip):
     b, m, k = SLAVES, RESOURCES, STEPS
-    text = _compile(_build_jax_fns(True)["place_run"], one_chip,
+    text = _compile(_build_jax_fns()["place_run"], one_chip,
                     ((b, m), jnp.float64), ((b, m), jnp.float64),
                     ((k, m), jnp.float64), ((k,), jnp.int64),
                     ((k,), jnp.int64), ((k,), jnp.int64))
-    assert "tpu_custom_call" in text
-    # The kernel keeps its stable name inside the fused program.
-    assert KERNEL_NAME in text
+    assert _has_sort(text)
+    assert "tpu_custom_call" not in text
 
 
 def test_probe_compiles(one_chip):
     n, m = 2048, RESOURCES
-    _compile(_build_jax_fns(True)["probe"], one_chip,
+    _compile(_build_jax_fns()["probe"], one_chip,
              ((n, m), jnp.float64), ((n,), jnp.float64),
              ((m,), jnp.float64))
 
@@ -69,7 +75,7 @@ def test_ladder_compiles(one_chip):
     # Small on purpose: the ladder's f64 argsort over n * L keys takes
     # minutes to compile for the TPU at n=1024, L=64.
     n, m, levels = 16, RESOURCES, 8
-    _compile(_build_jax_fns(True)["ladder"], one_chip,
+    _compile(_build_jax_fns()["ladder"], one_chip,
              ((n, m), jnp.float64), ((n,), jnp.int64), ((n,), jnp.int64),
              ((n,), jnp.float64), ((n,), jnp.bool_), ((m,), jnp.float64),
              ((levels,), jnp.int64))
